@@ -10,10 +10,12 @@ Three legs:
   ratio CI gates on.  On CPU the kernel runs in interpret mode, so the
   ratio is an availability/parity check there (< 1 is expected); on TPU
   it is the real fused-vs-scatter speedup;
-* a roofline record for BOTH programs via ``roofline/analysis.py``
-  (per-device HLO FLOPs/bytes from ``compiled.cost_analysis()`` against
-  the v5e peaks, plus the ideal model FLOPs/bytes of the level so
-  achieved-vs-ideal ratios are in the artifact).
+* on a TPU, a roofline record for BOTH programs via
+  ``roofline/analysis.py`` (per-device HLO FLOPs/bytes from
+  ``compiled.cost_analysis()`` against the running chip's published peaks,
+  ``launch.mesh.peaks``, plus the ideal model FLOPs/bytes of the level so
+  achieved-vs-ideal ratios are in the artifact).  Elsewhere there is no
+  chip to hold to a peak, and the record is left out.
 
 Exports ``RESULTS["kernels"]`` and (via run.py) ``BENCH_kernels.json``.
 """
@@ -52,7 +54,7 @@ def _lane_level_operands(rng, *, r, k, w):
 def _lane_probe_leg(quick: bool) -> None:
     from repro.kernels.lane_probe.ops import _on_tpu, lane_probe_level
     from repro.kernels.lane_probe.ref import lane_probe_level_ref
-    from repro.launch.mesh import HW
+    from repro.launch.mesh import peaks
     from repro.roofline.analysis import analyze
 
     rng = np.random.default_rng(0)
@@ -79,7 +81,7 @@ def _lane_probe_leg(quick: bool) -> None:
     emit("kernel/lane_probe_xla_oracle", t_xla * 1e6,
          f"shape={shape};fused_vs_xla_speedup={speedup:.3f}x")
 
-    # roofline: both programs against the v5e peaks. Ideal terms for one
+    # roofline: both programs against the chip's peaks. Ideal terms for one
     # level: 2 flops per (row, slot, lane) gather-accumulate plus the
     # weight multiply/exclusion, and one pass over every operand/result.
     model_flops = 2.0 * r * k * w + 2.0 * r * w
@@ -91,11 +93,12 @@ def _lane_probe_leg(quick: bool) -> None:
         + 4 * w            # lane vectors
     )
     roofline = {}
-    for name, fn in (("fused", fused), ("xla", oracle)):
+    hw = peaks(jax.devices()[0].device_kind) if _on_tpu() else None
+    for name, fn in ((("fused", fused), ("xla", oracle)) if hw else ()):
         compiled = fn.lower(*args).compile()
         rep = analyze(
             arch=f"lane_probe_{name}", shape=shape, mesh_name="single",
-            chips=1, compiled=compiled, model_flops=model_flops, hw=HW,
+            chips=1, compiled=compiled, model_flops=model_flops, hw=hw,
         )
         d = rep.to_dict()
         d["ideal_bytes"] = ideal_bytes

@@ -42,6 +42,10 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full or args.quick
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         bench_abserror,
         bench_dynamic,

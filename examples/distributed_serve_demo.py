@@ -25,7 +25,7 @@ from repro.graph import powerlaw_graph
 
 
 def main():
-    from repro.utils.jaxcompat import make_mesh, set_mesh, specs_to_shardings
+    from repro.utils.jaxcompat import make_mesh, specs_to_shardings
 
     mesh = make_mesh((2, 4), ("data", "model"))
     src, dst, n = powerlaw_graph(20_000, 200_000, seed=0)
@@ -37,7 +37,7 @@ def main():
     sg = build_sharded_graph(src, dst, n, pad_nodes=32, pad_edges=256)
     rg = build_ring_graph(src, dst, n, shards=4)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         auto = jax.jit(
             make_serve_step(cfg, queries=Q, walk_chunk=B, max_len=L, top_k=K,
                             edge_chunks=4),

@@ -65,7 +65,7 @@ from repro.graph.dynamic import (
     make_update_batch,
 )
 from repro.graph.partition import pad_to_multiple, partition_ops_by_dst
-from repro.utils.jaxcompat import make_mesh, set_mesh
+from repro.utils.jaxcompat import make_mesh
 
 Array = jax.Array
 
@@ -904,6 +904,7 @@ class ShardedBackend:
             shards=self.state.shards,
             capacity_per_shard=self.state.capacity_per_shard,
             k_max=max(deg_cap + 8, 16),
+            mesh=self.mesh,
         )
         self._epoch_graph = st
         self._epoch_sync = self.state.mutations
@@ -952,7 +953,7 @@ class ShardedBackend:
         b_src = np.asarray(batch.src)
         b_dst = np.asarray(batch.dst)
         b_ins = np.asarray(batch.insert)
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             if q:
                 out = step(st, batch, jnp.asarray(us, jnp.int32), keys)
             else:
@@ -1031,7 +1032,7 @@ class ShardedBackend:
                 frontier_dtype=self.frontier_dtype,
             )
             self._steps[cfg] = step
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             est, idx, vals = step(
                 st, *ring_args, jnp.asarray(us), jnp.asarray(keys)
             )

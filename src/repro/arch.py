@@ -35,7 +35,6 @@ from repro.configs.base import (
 )
 from repro.graph.sampler import block_shapes
 from repro.models.common import resolve_axis
-from repro.utils.jaxcompat import get_abstract_mesh
 from repro.training.optimizer import AdamW, warmup_cosine_schedule
 
 Array = jax.Array
@@ -77,8 +76,8 @@ def _all_axes():
 
 
 def _extent(axes) -> int:
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty or axes is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or axes is None:
         return 1
     out = 1
     for a in axes if isinstance(axes, tuple) else (axes,):

@@ -26,25 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import constrain, logical_spec, mesh_axis_names
-from repro.utils.jaxcompat import legacy_auto_partitioner
+from repro.models.common import constrain, mesh_axis_names
 from repro.utils.pytree import static, struct
 
 Array = jax.Array
-
-
-def _constrain(x: Array, *logical: str | None) -> Array:
-    """Frontier placement hint for the auto partitioner.
-
-    Old jax's SPMD partitioner double-counts scatter contributions when the
-    scatter operand is row-sharded by an explicit constraint (see
-    jaxcompat.legacy_auto_partitioner) — there the hints are dropped and
-    placement is left to the partitioner, which is correct (tested in
-    tests/test_distributed.py) if less deliberate.
-    """
-    if legacy_auto_partitioner():
-        return x
-    return constrain(x, *logical)
 
 
 @struct
@@ -100,18 +85,12 @@ def build_sharded_graph(
 
 def graph_specs(sg: ShardedGraph) -> ShardedGraph:
     """PartitionSpec pytree matching ShardedGraph (static fields copied —
-    pytree treedefs include the static metadata).
-
-    On old jax ``in_deg`` is replicated: the legacy partitioner mis-scales
-    the probe's ``concat(inv_in_deg, pad) * acc`` renormalization by the
-    axis extent when ``in_deg`` arrives row-sharded (same family of bug as
-    the ``_constrain`` gate above; [n_pad] int32 is cheap to replicate).
-    """
+    pytree treedefs include the static metadata)."""
     tp = "model" if "model" in mesh_axis_names() else None
     all_axes = tuple(a for a in ("pod", "data", "model") if a in mesh_axis_names())
     return ShardedGraph(
         indptr=P(tp),
-        in_deg=P(None) if legacy_auto_partitioner() else P(tp),
+        in_deg=P(tp),
         indices=P(all_axes if all_axes else None),
         src=P(all_axes if all_axes else None),
         dst=P(all_axes if all_axes else None),
@@ -185,7 +164,7 @@ def _push_chunked(
     acc = jnp.zeros_like(scores)
     for ci in range(edge_chunks):
         msgs = scores[src[ci].clip(0, n_pad)]  # [mc, C]; sentinel row zero
-        msgs = _constrain(msgs, "tp", "dp")
+        msgs = constrain(msgs, "tp", "dp")
         acc = acc + jax.ops.segment_sum(
             msgs, dst[ci], num_segments=rows_total
         )
@@ -217,7 +196,7 @@ def probe_walks_sharded(
     rows_total = n_pad + _row_pad(sg)
     rows = jax.lax.broadcasted_iota(jnp.int32, (rows_total, C), 0)
     scores = jnp.zeros((rows_total, C), jnp.float32)
-    scores = _constrain(scores, "tp", "dp")
+    scores = constrain(scores, "tp", "dp")
     for p in range(L, 1, -1):
         u_p = walks[:, p - 1]  # sentinel (>= n_pad) never matches a live row
         u_prev = walks[:, p - 2]
@@ -227,7 +206,7 @@ def probe_walks_sharded(
             scores = jnp.where(scores > thresh, scores, 0.0)
         scores = _push_chunked(sg, scores, sqrt_c, edge_chunks)
         scores = jnp.where(rows == u_prev[None, :], 0.0, scores)
-        scores = _constrain(scores, "tp", "dp")
+        scores = constrain(scores, "tp", "dp")
     return scores[:n_pad]
 
 
@@ -321,13 +300,18 @@ def lane_probe_block(
         pos = jnp.where(active, pos - 1, pos)
         return step + 1, pos, widx, next_q, scores, total
 
+    # the frontier blocks differ per model shard: their initial carries
+    # must be typed as varying over "model" to match the loop body
+    block = jax.lax.pcast(
+        jnp.zeros((rows, w), jnp.float32), "model", to="varying"
+    )
     state = (
         jnp.int32(0),
         jnp.zeros(w, jnp.int32),  # pos: all idle -> first iteration refills
         jnp.zeros(w, jnp.int32),  # widx
         jnp.zeros(q, jnp.int32),  # next_q
-        jnp.zeros((rows, w), jnp.float32),  # scores block
-        jnp.zeros((rows, w), jnp.float32),  # total block
+        block,  # scores block
+        block,  # total block
     )
     step, pos, _, _, scores, total = jax.lax.while_loop(cond, body, state)
     # safety-net flush (no-op unless max_steps was hit)
@@ -388,8 +372,6 @@ def probe_lanes_sharded(
     deposits and the carried block stay fp32, and the single-shard
     degenerate path skips the exchange (and the rounding) entirely.
     """
-    from repro.utils.jaxcompat import shard_map
-
     # sort each shard's bucket by source id, once per serve call: the push
     # gathers frontier rows in ascending-address order (cache-line reuse on
     # the [n_pad, W] gathered table) instead of FIFO-random, and sentinel
@@ -471,10 +453,11 @@ def probe_lanes_sharded(
                         full[s_c], d_c, num_segments=rows + 1
                     )
 
-                acc = jax.lax.fori_loop(
-                    0, n_chunks, chunk,
+                acc0 = jax.lax.pcast(
                     jnp.zeros((rows + 1, scores.shape[1]), jnp.float32),
-                )[:rows]
+                    "model", to="varying",
+                )
+                acc = jax.lax.fori_loop(0, n_chunks, chunk, acc0)[:rows]
                 return acc * w_l[:, None]
 
             level_fn = lane_level_xla(
@@ -499,15 +482,15 @@ def probe_lanes_sharded(
         in_specs.append(P("model", None))
         args.append(in_nbrs)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P("model", None),
-        # fully manual (same reason as the epoch apply step: leftover auto
-        # axes lower axis_index to a PartitionId old-jax rejects); inputs
-        # and compute replicate over the data axes
+        # fully manual; inputs and compute replicate over the data axes
         axis_names=set(mesh.axis_names),
+        # the Pallas interpreter cannot slice blocks of varying operands
+        check_vma=not use_kernel,
     )
     return fn(*args)
 
@@ -542,7 +525,7 @@ def make_serve_step(cfg, *, queries: int, walk_chunk: int, max_len: int,
             sg, walks, sqrt_c=sqrt_c, edge_chunks=edge_chunks
         )  # [n_pad, Q*B]
         est = scores.reshape(sg.n_pad, queries, walk_chunk).sum(-1) / walk_chunk
-        est = _constrain(est, "tp", None)
+        est = constrain(est, "tp", None)
         # exclude the query nodes themselves (compare, not scatter)
         rows = jax.lax.broadcasted_iota(jnp.int32, est.shape, 0)
         est = jnp.where(rows == query_nodes[None, :], -jnp.inf, est)

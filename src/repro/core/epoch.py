@@ -55,7 +55,7 @@ from repro.core.multisource import fused_serve_impl
 from repro.graph.dynamic import UpdateBatch, apply_update_batch
 from repro.graph.partition import pad_to_multiple
 from repro.graph.structs import EllGraph
-from repro.utils.jaxcompat import shard_map, specs_to_shardings
+from repro.utils.jaxcompat import specs_to_shardings
 from repro.utils.pytree import static, struct
 
 Array = jax.Array
@@ -193,9 +193,8 @@ class ShardEpochGraph:
       directly and draws bit-identical walks to the local mirror under
       shared keys;
     * ``in_deg`` int32 [n_pad] — replicated (it is the probe's
-      renormalization operand; [n_pad] int32 is cheap, and the legacy
-      auto partitioner mis-scales the renorm when it arrives sharded —
-      see ``core.distributed.graph_specs``).
+      renormalization operand and the sampler's degree table; [n_pad]
+      int32 is cheap).
 
     Updates preserve the invariant that the buffers are bit-identical to
     :func:`build_shard_epoch_graph` rebuilt from the equivalently-updated
@@ -226,6 +225,7 @@ def build_shard_epoch_graph(
     shards: int,
     capacity_per_shard: int,
     k_max: int,
+    mesh=None,
 ) -> ShardEpochGraph:
     """Build the device epoch state from a shard-major host edge list.
 
@@ -233,7 +233,9 @@ def build_shard_epoch_graph(
     ``ShardedGraphState.to_host_edges`` produces — re-partitioning that
     order is the identity, so incremental maintenance and this builder
     agree bit-for-bit).  ``k_max`` caps ELL rows; the max in-degree must
-    fit.
+    fit.  With ``mesh`` every buffer is placed straight into its
+    :func:`shard_epoch_specs` sharding (each device receives only its own
+    rows); without it the buffers land on the default device.
     """
     src = np.asarray(src, np.int32).reshape(-1)
     dst = np.asarray(dst, np.int32).reshape(-1)
@@ -270,14 +272,16 @@ def build_shard_epoch_graph(
     group_start = np.searchsorted(d_sorted, np.arange(n))
     idx_within = np.arange(len(d_sorted)) - group_start[d_sorted]
     table[d_sorted, idx_within] = s_sorted
-    return ShardEpochGraph(
-        src_sh=jnp.asarray(src_sh),
-        dst_sh=jnp.asarray(dst_sh),
-        counts=jnp.asarray(counts),
-        in_nbrs=jnp.asarray(table),
-        in_deg=jnp.asarray(in_deg),
+    st = ShardEpochGraph(
+        src_sh=src_sh, dst_sh=dst_sh, counts=counts, in_nbrs=table,
+        in_deg=in_deg,
         n=int(n), n_pad=int(n_pad), rows=int(rows), shards=int(shards),
         capacity=E, k_max=int(k_max),
+    )
+    if mesh is None:
+        return jax.tree.map(jnp.asarray, st)
+    return jax.device_put(
+        st, specs_to_shardings(shard_epoch_specs(st), mesh=mesh)
     )
 
 
@@ -426,7 +430,7 @@ def _shard_apply(st: ShardEpochGraph, batch: UpdateBatch, mesh):
             applied[None], ovf[None],
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -437,11 +441,8 @@ def _shard_apply(st: ShardEpochGraph, batch: UpdateBatch, mesh):
             P("model", None), P("model", None), P("model"),
             P("model", None), P("model", None), P("model"),
         ),
-        # fully manual: with auto axes left over, axis_index lowers to a
-        # PartitionId instruction old-jax's SPMD partitioner rejects (the
-        # ring probe runs fully manual for the same reason).  Inputs and
-        # compute are replicated over the data axes, so every data shard
-        # produces identical output tiles
+        # fully manual: inputs and compute are replicated over the data
+        # axes, so every data shard produces identical output tiles
         axis_names=set(mesh.axis_names),
     )
     src2, dst2, cnt2, ell2, applied_sh, ovf_sh = fn(
@@ -620,11 +621,15 @@ def make_sharded_epoch_step(
         return est, None, None
 
     run = epoch_pipeline(apply_stage, probe_stage if q else None)
+    state_shardings = specs_to_shardings(shard_epoch_specs(st), mesh=mesh)
 
     def step(state, batch, us=None, keys=None):
         state2, (applied, overflow), out = run(
             state, batch, (us, keys) if q else None
         )
+        # the carried state leaves with the placement it came in with, so
+        # the next epoch's in_shardings accept it
+        state2 = jax.lax.with_sharding_constraint(state2, state_shardings)
         if out is None:
             return state2, applied, overflow, None, None, None
         est, idx, vals = out

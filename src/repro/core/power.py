@@ -42,8 +42,12 @@ def simrank_power(g: Graph, *, c: float = 0.6, iters: int = 55) -> Array:
     n = g.n
     eye = jnp.eye(n, dtype=jnp.float32)
 
+    # fp32 matmuls: the default precision runs bf16 passes on a TPU, which
+    # would leave the reference good to only ~1e-3
+    hi = jax.lax.Precision.HIGHEST
+
     def body(_, S):
-        S = c * (P.T @ S @ P)
+        S = c * jnp.matmul(jnp.matmul(P.T, S, precision=hi), P, precision=hi)
         return jnp.maximum(S, eye)
 
     return jax.lax.fori_loop(0, iters, body, eye)

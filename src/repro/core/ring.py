@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import mesh_axis_names
-from repro.utils.jaxcompat import get_abstract_mesh, shard_map
 from repro.utils.pytree import static, struct
 
 Array = jax.Array
@@ -85,18 +84,13 @@ def ring_graph_abstract(n: int, m: int, shards: int, e_max: int) -> RingGraph:
 
 
 def ring_graph_specs(rg: RingGraph) -> RingGraph:
-    # in_deg replicated on old jax: see core.distributed.graph_specs (the
-    # legacy auto partitioner mis-scales the inv-in-degree renormalization
-    # when it arrives row-sharded; w_full is computed in the auto region)
-    from repro.utils.jaxcompat import legacy_auto_partitioner
-
     tp = "model" if "model" in mesh_axis_names() else None
     all_axes = tuple(a for a in ("pod", "data", "model")
                      if a in mesh_axis_names())
     return RingGraph(
         src_sh=P(tp, None, None),
         dst_sh=P(tp, None, None),
-        in_deg=P(None) if legacy_auto_partitioner() else P(tp),
+        in_deg=P(tp),
         indptr=P(tp),
         indices=P(all_axes if all_axes else None),
         n=rg.n, n_pad=rg.n_pad, m=rg.m, shards=rg.shards,
@@ -150,9 +144,10 @@ def _ring_push_level(buf, src_l, dst_l, me, *, shards: int, rows: int,
                     frontier[s_c], d_c, num_segments=rows + 1
                 )
 
-            acc = acc + jax.lax.fori_loop(
-                0, n_chunks, chunk, jnp.zeros((rows + 1, C), jnp.float32)
-            )[:rows]
+            acc0 = jax.lax.pcast(
+                jnp.zeros((rows + 1, C), jnp.float32), "model", to="varying"
+            )
+            acc = acc + jax.lax.fori_loop(0, n_chunks, chunk, acc0)[:rows]
         if step < shards - 1:
             # permute raw bits: XLA's algebraic simplifier otherwise
             # elides the f32->bf16->f32 round-trip and widens the
@@ -180,7 +175,7 @@ def probe_walks_ring(
     n_pad = rg.n_pad
     rows = n_pad // S
     C, L = walks.shape
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
 
     w_full = jnp.where(
         rg.in_deg > 0,
@@ -215,7 +210,7 @@ def probe_walks_ring(
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     col_spec = data_axes if data_axes else None
     manual = {"model"} | set(data_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(col_spec, None), P("model", None, None),
@@ -264,7 +259,6 @@ def probe_lanes_ring(
     XLA compare injects) land in the dropped scatter segment.
     """
     from repro.core.distributed import lane_level_xla, lane_probe_block
-    from repro.utils.jaxcompat import shard_map
 
     edge_chunk = 2048
     E = src_sh.shape[2]
@@ -328,7 +322,7 @@ def probe_lanes_ring(
             max_len=max_len, sqrt_c=sqrt_c, eps_p=eps_p, sentinel=sentinel,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None, None), P("model", None, None),
@@ -336,6 +330,8 @@ def probe_lanes_ring(
         out_specs=P("model", None),
         # fully manual, like the epoch apply step and the spmd lane probe
         axis_names=set(mesh.axis_names),
+        # the Pallas interpreter cannot slice blocks of varying operands
+        check_vma=not use_kernel,
     )
     return fn(src_sh, dst_sh, w_full, pool, pool_len)
 

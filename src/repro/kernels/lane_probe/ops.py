@@ -10,6 +10,10 @@ callers never see it:
 * lane columns pad up to the 128-wide lane dimension (sentinel u_p/u_prev,
   ``fin`` false, zero thresholds — padded columns are no-ops);
 * ``fin`` booleans widen to int32 for the kernel operand;
+* the row block shrinks (down to 8 rows) until the id blocks fit SMEM, and
+  a shape whose blocks fit no on-chip budget raises ``ValueError`` — the
+  kernel keeps the whole gather table in VMEM, so large graphs must run
+  with ``use_kernel=False``;
 * ``row0``/``tab0`` (global id of output row 0 / its table row) may be
   python ints or traced values (the sharded paths call this inside
   shard_map with a per-shard ``row0``).
@@ -24,7 +28,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.lane_probe.lane_probe import lane_probe_pallas
+from repro.kernels.lane_probe.lane_probe import (
+    SMEM_LIMIT_BYTES,
+    VMEM_LIMIT_BYTES,
+    kernel_bytes,
+    lane_probe_pallas,
+)
 
 Array = jax.Array
 
@@ -42,6 +51,28 @@ def _pad_rows(r: int, block_rows: int) -> tuple[int, int]:
     if rp >= block_rows:
         return -(-rp // block_rows) * block_rows, block_rows
     return rp, rp
+
+
+def _fit_block(r: int, k: int, t: int, w: int, itemsize: int,
+               block_rows: int) -> tuple[int, int]:
+    """(padded_rows, block) whose kernel blocks fit SMEM and VMEM; raises
+    ``ValueError`` when no block of 8 rows or more does."""
+    bn = block_rows
+    while True:
+        rp, b = _pad_rows(r, bn)
+        vmem, smem = kernel_bytes(block_rows=b, k_slots=k, table_rows=t,
+                                  width=w, itemsize=itemsize)
+        if smem <= SMEM_LIMIT_BYTES and vmem <= VMEM_LIMIT_BYTES:
+            return rp, b
+        if smem <= SMEM_LIMIT_BYTES or b <= 8:
+            raise ValueError(
+                f"lane-probe kernel blocks do not fit on chip for "
+                f"[{r} rows, K={k}] over a [{t}, {w}] table: {vmem} B of "
+                f"VMEM (limit {VMEM_LIMIT_BYTES}) and {smem} B of SMEM "
+                f"(limit {SMEM_LIMIT_BYTES}) at {b}-row blocks; run this "
+                f"graph with use_kernel=False"
+            )
+        bn = max(8, b // 16 * 8)
 
 
 def lane_probe_level(
@@ -68,8 +99,9 @@ def lane_probe_level(
     if interpret is None:
         interpret = not _on_tpu()
 
-    rp, bn = _pad_rows(r, block_rows)
     wp = -(-w // _LANE) * _LANE
+    rp, bn = _fit_block(r, nbrs.shape[1], table.shape[0], wp,
+                        table.dtype.itemsize, block_rows)
     dtype = table.dtype
 
     if rp != r:

@@ -35,7 +35,7 @@ from repro import arch as arch_mod
 from repro.configs.base import ARCH_IDS, get_config, shapes_for
 from repro.launch.mesh import HW, make_production_mesh
 from repro.roofline import analysis as ra
-from repro.utils.jaxcompat import set_mesh, specs_to_shardings
+from repro.utils.jaxcompat import specs_to_shardings
 
 
 def abstract_state(bundle):
@@ -48,7 +48,7 @@ def abstract_state(bundle):
 
 
 def lower_and_compile(bundle, mesh):
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = abstract_state(bundle)
         state_specs = bundle.state_specs(state)
         in_shard = bundle.input_shardings()
@@ -104,7 +104,7 @@ def run_cell(arch_id: str, shape_name: str, mesh_name: str, *,
             cfg_o = dataclasses.replace(
                 cfg_o, moe=dataclasses.replace(cfg_o.moe, **moe_over))
         # mesh context needed for probesim shard-count-dependent init
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             bundle = arch_mod.build_with_cfg(arch_id, cfg_o, bundle.shape)
         record["overrides"] = {k: str(v) for k, v in overrides.items()}
     cfg = bundle.cfg

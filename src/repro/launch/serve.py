@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.api import GraphHandle, QuerySpec, SimRankSession
 from repro.serving.straggler import HedgePolicy, dispatch, dispatch_adaptive
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -89,6 +90,7 @@ def main() -> None:
                     help="--serve: admission bound; past it clients get "
                          "429 + Retry-After")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.epsilon is not None and args.epochs:
         ap.error("--epsilon and --epochs are mutually exclusive: --epsilon "
                  "queries are served by the host-side escalation loop and "

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.jaxcompat import get_abstract_mesh
 
 Array = jax.Array
 
@@ -24,8 +23,8 @@ Array = jax.Array
 
 
 def mesh_axis_names() -> tuple[str, ...]:
-    mesh = get_abstract_mesh()
-    return tuple(mesh.axis_names) if mesh is not None and not mesh.empty else ()
+    mesh = jax.sharding.get_abstract_mesh()
+    return () if mesh.empty else tuple(mesh.axis_names)
 
 
 def resolve_axis(logical: str | None):
@@ -47,8 +46,8 @@ def logical_spec(*logical: str | None) -> P:
 
 def axis_size(logical: str) -> int:
     """Product of mesh extents behind a logical axis (1 if absent)."""
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1
     concrete = resolve_axis(logical)
     if concrete is None:
@@ -77,7 +76,7 @@ def constrain(x: Array, *logical: str | None) -> Array:
     unconstrained (e.g. 8 KV heads under 16-way TP)."""
     if not mesh_axis_names():
         return x
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     spec = []
     for dim, name in zip(x.shape, logical):
         ax = resolve_axis(name)

@@ -1,139 +1,37 @@
-"""Version-compat shims over jax's mesh / sharding surface.
-
-The distributed substrate was written against the post-0.5 jax mesh API
-(`jax.set_mesh`, `jax.shard_map`, `jax.sharding.get_abstract_mesh`,
-`jax.make_mesh(..., axis_types=...)`).  The pinned container jax (0.4.x)
-predates all four, which left every meshed code path — the sharded probe,
-the ring push, the arch-bundle sharding helpers — unimportable.  This
-module is the ONE place that knows which spelling the running jax uses;
-everything else imports from here:
-
-    from repro.utils.jaxcompat import (
-        get_abstract_mesh, make_mesh, set_mesh, shard_map, specs_to_shardings,
-    )
-
-Semantics (identical on both jax generations):
+"""Mesh and sharding helpers shared by every meshed code path.
 
 * ``make_mesh(shape, axes)`` — a mesh over the local devices with Auto
   axis types (explicit-sharding mode is never used here);
-* ``set_mesh(mesh)`` — context manager making ``mesh`` the active mesh for
-  spec resolution (`jax.set_mesh` when it exists, the legacy ``with mesh:``
-  resource env otherwise);
-* ``get_abstract_mesh()`` — the active mesh or None when there is none
-  (old jax has no always-empty AbstractMesh to return, hence the None
-  convention; callers treat None and ``mesh.empty`` alike);
-* ``shard_map(f, mesh=..., in_specs=..., out_specs=..., axis_names=...)``
-  — the new-style signature; on old jax ``axis_names`` is translated to
-  the complementary ``auto=`` set and per-output replication checking is
-  disabled (old check_rep rejects collectives the new checker accepts);
 * ``specs_to_shardings(tree, mesh=...)`` — maps a PartitionSpec pytree to
-  NamedShardings.  New jax accepts bare specs in ``jit``'s
-  ``in_shardings`` under an active mesh; old jax requires concrete
-  ``Sharding`` objects, so meshed ``jit`` call sites route their spec
-  trees through this helper (a no-op wrap on new jax too — NamedSharding
-  is accepted everywhere).
+  NamedShardings for ``jit``'s ``in_shardings``.
+
+Everything else is spelled the jax way at the call site: ``jax.set_mesh``,
+``jax.sharding.get_abstract_mesh`` and ``jax.shard_map``.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_GET_ABSTRACT = hasattr(jax.sharding, "get_abstract_mesh")
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-
-
-def legacy_auto_partitioner() -> bool:
-    """True on old jax, whose auto (SPMD) partitioner double-counts scatter
-    contributions when the scatter operand carries an explicit row-sharding
-    constraint (observed: segment_sum results scaled by the axis extent).
-
-    Callers that add placement *hints* for the auto partitioner (the
-    distributed probe's frontier constraints) skip them on old jax — the
-    partitioner then picks placements itself, which is slower but correct.
-    Manual paths (shard_map ring) are unaffected.
-    """
-    return not _HAS_SET_MESH
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """Auto-axis mesh over the local devices, on either jax generation."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Make ``mesh`` the active mesh for PartitionSpec resolution."""
-    if _HAS_SET_MESH:
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        # legacy resource env: activates the mesh for pjit/shard_map spec
-        # resolution and for get_abstract_mesh() below
-        with mesh:
-            yield mesh
-
-
-def get_abstract_mesh():
-    """The active mesh, or None if none is set (old jax has no empty
-    AbstractMesh singleton to hand back)."""
-    if _HAS_GET_ABSTRACT:
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib
-
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """New-style shard_map signature on either jax generation.
-
-    ``axis_names`` is the set of mesh axes that are MANUAL inside ``f``
-    (the new-jax meaning); old jax expresses the same thing as the
-    complementary ``auto`` set.
-    """
-    if _HAS_NEW_SHARD_MAP:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = (
-        frozenset(mesh.axis_names) - set(axis_names)
-        if axis_names is not None
-        else frozenset()
-    )
-    # check_rep=False: the legacy replication checker rejects patterns
-    # (psum-of-segment_sum, bitcast ppermute) the new one accepts
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        auto=auto, check_rep=False,
-    )
+    """Auto-axis mesh over the local devices."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def specs_to_shardings(tree, *, mesh=None):
     """PartitionSpec pytree -> NamedSharding pytree against ``mesh``.
 
     ``mesh`` defaults to the active mesh.  None leaves mean "replicated"
-    (NamedSharding(mesh, P())), matching what new jax infers for a bare
-    None in ``in_shardings`` under a mesh.
+    (NamedSharding(mesh, P())).
     """
-    mesh = mesh if mesh is not None else get_abstract_mesh()
     if mesh is None:
-        raise ValueError("specs_to_shardings needs a mesh (none active)")
-    # old jax: the thread-resource mesh is already concrete; new jax may
-    # hand back an AbstractMesh — NamedSharding wants the concrete one
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            raise ValueError("specs_to_shardings needs a mesh (none active)")
+    # an active mesh may come back abstract; NamedSharding wants the
+    # concrete one
     concrete = getattr(mesh, "_concrete_mesh", None) or mesh
     return jax.tree.map(
         lambda s: NamedSharding(concrete, s if s is not None else P()),

@@ -32,7 +32,7 @@ import jax, jax.numpy as jnp
 from repro.configs.base import ProbeSimConfig
 from repro.core.distributed import build_sharded_graph, make_serve_step, graph_specs
 from repro.graph import powerlaw_graph
-from repro.utils.jaxcompat import make_mesh, set_mesh, specs_to_shardings
+from repro.utils.jaxcompat import make_mesh, specs_to_shardings
 from jax.sharding import PartitionSpec as P
 
 mesh = make_mesh((2, 4), ("data", "model"))
@@ -43,7 +43,7 @@ serve = make_serve_step(cfg, queries=2, walk_chunk=32, max_len=6, top_k=8,
                         edge_chunks=4)
 queries = jnp.asarray([int(dst[0]), int(dst[1])], jnp.int32)
 key = jax.random.key(7)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     jf = jax.jit(serve, in_shardings=specs_to_shardings(
         (graph_specs(sg), P(), P()), mesh=mesh))
     idx, vals = jf(sg, queries, key)
@@ -112,14 +112,14 @@ import numpy as np
 import jax, jax.numpy as jnp
 from repro.core.ring import build_ring_graph, probe_walks_ring
 from repro.core.distributed import build_sharded_graph, probe_walks_sharded, sample_walks_sharded
-from repro.utils.jaxcompat import make_mesh, set_mesh
+from repro.utils.jaxcompat import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 from repro.graph import powerlaw_graph
 src, dst, n = powerlaw_graph(200, 1600, seed=3)
 rg = build_ring_graph(src, dst, n, shards=4)
 sg = build_sharded_graph(src, dst, n, pad_nodes=4, pad_edges=64)
 key = jax.random.key(5)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     walks = sample_walks_sharded(key, sg, jnp.asarray([int(dst[0])], jnp.int32),
                                  walks_per_query=16, max_len=6, sqrt_c=0.775)
     ref = probe_walks_sharded(sg, walks, sqrt_c=0.775, edge_chunks=4)

@@ -1,0 +1,147 @@
+"""Compile the main path for a described TPU v5e, without a chip.
+
+The TPU compiler is installed with jaxlib: it compiles for a described
+``v5e:2x2`` topology from shapes alone and refuses what the chip would
+refuse (unlowerable kernels, kernels over their memory budget, programs
+over the chip's HBM).  Nothing runs here, so these tests say nothing about
+results or times.  The topology is described inside a fixture (never at
+import) so every pytest worker collects the same tests.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+HBM_BYTES = 16 * 1024**3  # one TPU v5e chip
+# the hepph stand-in at its published n (paper_dataset("hepph", 1.0))
+HEPPH_N, HEPPH_K, HEPPH_M = 34_546, 34_541, 149_381
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_lane_kernel_lowers_at_bench_shape(one_chip):
+    from repro.kernels.lane_probe.lane_probe import lane_probe_pallas
+
+    R, K, T, W = 4096, 16, 4097, 256
+    i32, f32 = jnp.int32, jnp.float32
+    args = (
+        _sds((R, K), i32, one_chip), _sds((R,), f32, one_chip),
+        _sds((2,), i32, one_chip), *(_sds((W,), i32, one_chip),) * 3,
+        _sds((W,), f32, one_chip), _sds((T, W), f32, one_chip),
+        _sds((R, W), f32, one_chip), _sds((R, W), f32, one_chip),
+    )
+    compiled = jax.jit(
+        lambda *a: lane_probe_pallas(*a, n_live=R, prune=True,
+                                     interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_lane_kernel_refuses_hepph_k(one_chip):
+    from repro.kernels.lane_probe.ops import lane_probe_level
+
+    n, W = HEPPH_N, 256
+    args = (
+        _sds((n, HEPPH_K), jnp.int32, one_chip),
+        _sds((n,), jnp.float32, one_chip),
+        *(_sds(s, jnp.float32, one_chip) for s in ((n + 1, W), (n, W), (n, W))),
+        _sds((W,), jnp.bool_, one_chip),
+        *(_sds((W,), jnp.int32, one_chip),) * 2,
+        _sds((W,), jnp.float32, one_chip),
+    )
+    with pytest.raises(ValueError, match="use_kernel=False"):
+        jax.jit(
+            lambda *a: lane_probe_level(*a, row0=0, tab0=0, n_live=n,
+                                        prune=True, interpret=False)
+        ).lower(*args)
+
+
+def test_fused_serve_fits_one_chip_at_hepph(one_chip):
+    from repro.core.multisource import _fused_serve
+    from repro.core.params import make_params
+    from repro.graph.structs import EllGraph, Graph
+
+    n, k, m, q = HEPPH_N, HEPPH_K, HEPPH_M, 16
+    cap = m + 4096
+    i32 = jnp.int32
+    s = lambda shape, dt=i32: _sds(shape, dt, one_chip)  # noqa: E731
+    g = Graph(src=s((cap,)), dst=s((cap,)), in_deg=s((n,)), out_deg=s((n,)),
+              num_edges=s(()), n=n, capacity=cap, version=s(()),
+              overflow=s((), jnp.bool_))
+    eg = EllGraph(in_nbrs=s((n, k)), in_deg=s((n,)), n=n, k_max=k,
+                  version=s(()), overflow=s((), jnp.bool_))
+    keys = s((q,), jax.random.key(0).dtype)
+    p = make_params(n, c=0.6, eps_a=0.1, delta=0.01)
+    compiled = _fused_serve.lower(
+        keys, g, eg, s((q,)), s((q, n), jnp.float32),
+        n_r=p.n_r, lanes_q=16, max_len=p.max_len, sqrt_c=p.sqrt_c,
+        eps_p=p.eps_p, eps_t=p.eps_t, truncation_shift=False,
+        use_kernel=False, top_k=50,
+    ).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 4 * n * k <= used < HBM_BYTES
+
+
+def test_sharded_serve_step_compiles_on_four_chips(topo):
+    from repro.core.epoch import ShardEpochGraph, make_sharded_serve_step
+
+    mesh = jax.sharding.Mesh(
+        np.asarray(topo.devices).reshape(1, 4), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
+    n, shards, e, k_max, q = 4000, 4, 8192, 64, 16
+    sh = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    i32 = jnp.int32
+    st = ShardEpochGraph(
+        src_sh=_sds((shards, e), i32, sh(P("model", None))),
+        dst_sh=_sds((shards, e), i32, sh(P("model", None))),
+        counts=_sds((shards,), i32, sh(P("model"))),
+        in_nbrs=_sds((n, k_max), i32, sh(P("model", None))),
+        in_deg=_sds((n,), i32, sh(P())),
+        n=n, n_pad=n, rows=n // shards, shards=shards, capacity=e,
+        k_max=k_max,
+    )
+    step = make_sharded_serve_step(
+        st, mesh, q=q, n_r=512, lanes_q=16, top_k=10, max_len=12,
+        sqrt_c=0.775, eps_p=0.005, eps_t=0.05, truncation_shift=False,
+    )
+    compiled = step.lower(
+        st, _sds((q,), i32, sh(P())),
+        _sds((q,), jax.random.key(0).dtype, sh(P())),
+    ).compile()
+    hlo = compiled.as_text()
+    assert "all-gather" in hlo
