@@ -66,6 +66,7 @@ from repro.graph.dynamic import (
 )
 from repro.graph.partition import pad_to_multiple, partition_ops_by_dst
 from repro.utils.jaxcompat import make_mesh
+from repro.utils.spans import DISPATCH_FETCH, span
 
 Array = jax.Array
 
@@ -98,12 +99,15 @@ class Backend(Protocol):
     buffers) and the compiled serve steps; the session owns specs, PRNG
     streams, queues, stats and envelopes.  ``serve_batch`` is the one
     required query entry point (``serve_one`` has a default route through
-    it on both shipped backends); updates arrive as homogeneous
-    sub-batches (one ``insert`` flag per call, duplicate delete pairs
-    already split by the session) and return a per-op applied mask with
-    ``GraphHandle.apply_batch`` semantics: an unapplied insert means
-    capacity overflow (sticky ``overflow``, recover via ``regrow``), an
-    unapplied delete means the edge was absent.
+    it on both shipped backends) and returns ``(est, idx, vals,
+    levels)`` on the host, ``levels`` the probe levels the dispatch ran
+    or None where the backend does not count them; updates arrive as
+    homogeneous sub-batches (one ``insert`` flag per call, duplicate
+    delete pairs already split by the session) and return a per-op
+    applied mask with ``GraphHandle.apply_batch`` semantics: an
+    unapplied insert means capacity overflow (sticky ``overflow``,
+    recover via ``regrow``), an unapplied delete means the edge was
+    absent.
 
     Backends that set ``supports_epoch`` additionally implement the fused
     epoch stage (``core.epoch``): ``epoch_batch`` applies one padded
@@ -281,23 +285,30 @@ class LocalBackend:
     def serve_batch(
         self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int
     ) -> tuple:
-        """One fused multi-query dispatch; returns ``(est, idx, vals)``
-        (est for single_source kind, idx/vals for topk — the unused pair
-        is None).  Exactly one of ``keys`` ([Q] per-query streams) /
-        ``key`` (scalar: legacy split semantics) is set."""
+        """One fused multi-query dispatch; returns ``(est, idx, vals,
+        levels)`` (est for single_source kind, idx/vals for topk — the
+        unused side is None; ``levels`` is the probe levels the step ran).
+        Exactly one of ``keys`` ([Q] per-query streams) / ``key`` (scalar:
+        legacy split semantics) is set."""
         g, eg = self.handle.g, self.handle.eg
         us = jnp.asarray(us, jnp.int32)
+        info: dict = {}
         common = dict(
             lanes=self.walk_chunk, n_r=n_r, keys=keys,
             use_kernel=self.use_kernel, kernel_dtype=self.kernel_dtype,
+            info=info,
         )
         if kind == "topk":
             idx, vals = multi_source_topk(
                 key, g, eg, us, k, self.params, **common
             )
-            return None, np.asarray(idx), np.asarray(vals)
+            with span(DISPATCH_FETCH):  # one transfer, answers and count
+                idx, vals, levels = jax.device_get((idx, vals, info["levels"]))
+            return None, idx, vals, int(levels)
         est = multi_source(key, g, eg, us, self.params, **common)
-        return np.asarray(est), None, None
+        with span(DISPATCH_FETCH):
+            est, levels = jax.device_get((est, info["levels"]))
+        return est, None, None, int(levels)
 
     # -- updates -------------------------------------------------------------
 
@@ -974,7 +985,7 @@ class ShardedBackend:
     # -- queries -------------------------------------------------------------
 
     def serve_one(self, spec: QuerySpec, key, *, variant: str, n_r: int) -> dict:
-        est, idx, vals = self.serve_batch(
+        est, idx, vals, _ = self.serve_batch(
             spec.kind, [spec.node], jnp.stack([key]),
             k=spec.k or 0, n_r=n_r,
         )
@@ -995,6 +1006,8 @@ class ShardedBackend:
         ``drain()``/ticket serving reuses resident device state instead of
         rebuilding from host buffers).  Compiled once per
         (Q, k, n_r, probe, capacity band); zero host transfers mid-query.
+        Returns ``(est, idx, vals, None)``: the mesh step does not count
+        its probe levels.
         """
         us = np.asarray(us, np.int32).reshape(-1)
         q = us.shape[0]
@@ -1037,5 +1050,5 @@ class ShardedBackend:
                 st, *ring_args, jnp.asarray(us), jnp.asarray(keys)
             )
         if kind == "single_source":
-            return np.asarray(est), None, None
-        return None, np.asarray(idx), np.asarray(vals)
+            return np.asarray(est), None, None, None
+        return None, np.asarray(idx), np.asarray(vals), None

@@ -81,6 +81,7 @@ from repro.core.epoch import epoch_step  # noqa: F401  (re-exported: the
 #   serving.dynamic_engine among them — keep finding it here)
 from repro.core.params import ProbeSimParams, abs_error_bound, make_params
 from repro.graph.dynamic import UpdateBatch, make_update_batch
+from repro.utils.spans import DISPATCH, span
 
 Array = jax.Array
 
@@ -96,7 +97,8 @@ class EngineStats:
     ``escalations`` counts accuracy-controller rounds beyond the first
     (extra dispatches adaptive queries paid), ``hub_hits`` whole serve
     dispatches skipped because every row of an escalation round was
-    already in the hub probe cache.
+    already in the hub probe cache.  ``probe_levels`` totals the probe
+    levels of the fused serve dispatches whose backend counts them.
     """
 
     queries: int = 0
@@ -107,6 +109,7 @@ class EngineStats:
     regrows: int = 0
     escalations: int = 0
     hub_hits: int = 0
+    probe_levels: int = 0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -518,10 +521,13 @@ class SimRankSession:
     def _query_flat(self, spec: QuerySpec) -> ResultEnvelope:
         variant = self.plan(spec)
         n_r = spec.budget_walks or self.params.n_r
-        t0 = time.time()
+        levels = None
         if spec.nodes is None:
             key = spec.key if spec.key is not None else self._query_key()
-            out = self.backend.serve_one(spec, key, variant=variant, n_r=n_r)
+            with span(DISPATCH) as sp:
+                out = self.backend.serve_one(
+                    spec, key, variant=variant, n_r=n_r
+                )
         else:
             if variant != "telescoped":
                 raise ValueError(
@@ -529,26 +535,29 @@ class SimRankSession:
                     f"got variant={variant!r}"
                 )
             key, keys = self._multi_keys(spec)
-            est, idx, vals = self.backend.serve_batch(
-                spec.kind, spec.nodes, keys, key=key, k=spec.k or 0, n_r=n_r
-            )
+            with span(DISPATCH) as sp:
+                est, idx, vals, levels = self.backend.serve_batch(
+                    spec.kind, spec.nodes, keys, key=key, k=spec.k or 0,
+                    n_r=n_r,
+                )
             out = (
                 dict(scores=est)
                 if spec.kind == "single_source"
                 else dict(topk_nodes=idx, topk_scores=vals)
             )
-        dt = time.time() - t0
         self.stats.steps += 1
         self.stats.queries += spec.q
+        self.stats.probe_levels += levels or 0
         return ResultEnvelope(
             kind=spec.kind,
             node=spec.node,
             nodes=spec.nodes,
             walks_used=n_r,
-            latency_s=dt,
+            latency_s=sp.seconds,
             version=self.version,
             error_bound=self.error_bound(n_r),
             variant=self.backend.dispatch_label(variant),
+            probe_levels=levels,
             **out,
         )
 
@@ -619,6 +628,7 @@ class SimRankSession:
             certified_bound=worst.certified_bound,
             certificate=worst.certificate,
             rounds=max(e.rounds for e in envs),
+            probe_levels=envs[0].probe_levels,
         )
 
     def _serve_adaptive(
@@ -678,7 +688,8 @@ class SimRankSession:
                 streams.append(item[1])
                 cacheable.append(False)
         ver = self.version
-        t0 = time.time()
+        levels = None  # summed over the rounds that dispatched
+        t0 = time.perf_counter()
         while True:
             n_round = ctrl.next_round()
             if n_round is None:
@@ -688,7 +699,7 @@ class SimRankSession:
             if (
                 deadline_s is not None
                 and r > 0
-                and time.time() - t0 >= deadline_s
+                and time.perf_counter() - t0 >= deadline_s
             ):
                 ctrl.finish("deadline")
                 break
@@ -711,10 +722,14 @@ class SimRankSession:
                 keys = jnp.stack(
                     [jax.random.fold_in(s, r) for s in streams]
                 )
-                est, _, _ = self.backend.serve_batch(
-                    "single_source", us, keys, k=0, n_r=n_round
-                )
+                with span(DISPATCH):
+                    est, _, _, lv = self.backend.serve_batch(
+                        "single_source", us, keys, k=0, n_r=n_round
+                    )
                 est = np.asarray(est)
+                if lv is not None:
+                    levels = (levels or 0) + lv
+                    self.stats.probe_levels += lv
                 self.stats.steps += 1
                 if r > 0:
                     self.stats.escalations += 1
@@ -724,7 +739,7 @@ class SimRankSession:
             ctrl.absorb(n_round, est)
             if ctrl.all_frozen:
                 break
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         label = self.backend.dispatch_label("telescoped")
         out = []
         for i, item in enumerate(batch):
@@ -742,6 +757,7 @@ class SimRankSession:
                 certified_bound=cert.bound,
                 certificate=cert.name,
                 rounds=cert.rounds,
+                probe_levels=levels,
             )
             if sp.kind == "single_source":
                 env.scores = scores
@@ -831,12 +847,12 @@ class SimRankSession:
         n_r = spec0.budget_walks or budget_walks or self.params.n_r
         us = [item[0].node for item in batch]
         keys = jnp.stack([item[1] for item in batch])
-        t0 = time.time()
-        est, idx, vals = self.backend.serve_batch(
-            spec0.kind, us, keys, k=spec0.k or 0, n_r=n_r
-        )
-        dt = time.time() - t0
+        with span(DISPATCH) as sp:
+            est, idx, vals, levels = self.backend.serve_batch(
+                spec0.kind, us, keys, k=spec0.k or 0, n_r=n_r
+            )
         self.stats.steps += 1
+        self.stats.probe_levels += levels or 0
         ver = self.version
         bound = self.error_bound(n_r)
         return [
@@ -847,10 +863,11 @@ class SimRankSession:
                 topk_nodes=None if est is not None else idx[i],
                 topk_scores=None if est is not None else vals[i],
                 walks_used=n_r,
-                latency_s=dt,
+                latency_s=sp.seconds,
                 version=ver,
                 error_bound=bound,
                 variant=self.backend.dispatch_label("telescoped"),
+                probe_levels=levels,
             )
             for i, item in enumerate(batch)
         ]

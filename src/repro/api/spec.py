@@ -119,6 +119,12 @@ class ResultEnvelope:
     escalation stopped without meeting epsilon (``budget`` / ``deadline``),
     and ``rounds`` counts the escalation rounds executed.
 
+    ``probe_levels`` is the number of probe levels the fused serve
+    dispatch ran (summed over the rounds of an adaptive query; every
+    answer of one dispatch carries the same count), or None where the
+    path does not count them (one-shot legacy variants, fused epochs,
+    the sharded backend).
+
     Field-superset of the legacy ``QueryResult`` — engine shims return
     envelopes directly.
     """
@@ -138,3 +144,4 @@ class ResultEnvelope:
     certified_bound: float = float("nan")
     certificate: str | None = None
     rounds: int = 1
+    probe_levels: int | None = None

@@ -158,7 +158,7 @@ def epoch_step(
         lambda state, b: _pair_apply(state, b), probe
     )
     (g2, eg2), applied, out = run((g, eg), batch, (keys, us, acc))
-    acc, est, idx, vals = out
+    acc, est, idx, vals, _ = out  # the level count is not reported here
     return g2, eg2, applied, est, idx, vals
 
 

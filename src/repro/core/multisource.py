@@ -174,8 +174,9 @@ def fused_serve_impl(
     lane-probe kernel (``kernels/lane_probe``) against the ELL push table;
     ``kernel_dtype="bfloat16"`` additionally stores the score/accumulator
     buffers in bf16 (accumulation stays fp32 on-chip).  Returns
-    ``(acc, est, topk_idx, topk_vals)``; the top-k outputs are None when
-    ``top_k == 0``.
+    ``(acc, est, topk_idx, topk_vals, levels)``; the top-k outputs are
+    None when ``top_k == 0``.  ``levels`` (int32 scalar) counts the probe
+    levels the step ran: the peeled first level plus the loop's trips.
     """
     n = eg.n
     q = us.shape[0]
@@ -282,7 +283,7 @@ def fused_serve_impl(
         pool = head.reshape(q * n_r, max_len)
         pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
         state = body(state, pool, pool_len)
-    step, pos, _, _, scores, total = jax.lax.while_loop(
+    levels, pos, _, _, scores, total = jax.lax.while_loop(
         cond, lambda s: body(s, pool, pool_len), state
     )
     # safety-net flush (no-op unless max_steps was hit)
@@ -297,8 +298,8 @@ def fused_serve_impl(
     if top_k > 0:
         masked = est.at[jnp.arange(q), us].set(-jnp.inf)
         vals, idx = jax.lax.top_k(masked, top_k)
-        return acc, est, idx, vals
-    return acc, est, None, None
+        return acc, est, idx, vals, levels
+    return acc, est, None, None, levels
 
 
 # The standalone jitted entry point.  ``fused_serve_impl`` stays un-jitted so
@@ -332,6 +333,35 @@ def _query_keys(key: Array | None, keys: Array | None, q: int) -> Array:
     return jax.random.split(key, q)
 
 
+def _serve(
+    key, g, eg, us, params, *, k, lanes, use_kernel, kernel_dtype, n_r,
+    keys, info,
+):
+    """One jitted fused step for :func:`multi_source` (``k == 0``) and
+    :func:`multi_source_topk`; returns ``(est, idx, vals)`` on the device
+    and puts the step's probe-level count (int32 scalar) in
+    ``info["levels"]`` when ``info`` is a dict."""
+    us = jnp.asarray(us, jnp.int32)
+    q = int(us.shape[0])
+    acc = jnp.zeros((q, g.n), jnp.float32)
+    _, est, idx, vals, levels = _fused_serve(
+        _query_keys(key, keys, q), g, eg, us, acc,
+        n_r=int(n_r or params.n_r),
+        lanes_q=max(1, lanes // q),
+        max_len=params.max_len,
+        sqrt_c=params.sqrt_c,
+        eps_p=params.eps_p,
+        eps_t=params.eps_t,
+        truncation_shift=params.truncation_shift,
+        use_kernel=use_kernel,
+        top_k=int(k),
+        kernel_dtype=kernel_dtype,
+    )
+    if info is not None:
+        info["levels"] = levels
+    return est, idx, vals
+
+
 def multi_source(
     key: Array | None,
     g: Graph | EllGraph,
@@ -344,6 +374,7 @@ def multi_source(
     kernel_dtype: str = "float32",
     n_r: int | None = None,
     keys: Array | None = None,
+    info: dict | None = None,
 ) -> Array:
     """Fused multi-query single-source SimRank: estimates [Q, n].
 
@@ -356,23 +387,12 @@ def multi_source(
     accumulation.  ``n_r`` overrides ``params.n_r`` (anytime/budgeted
     serving).  Pass per-query ``keys`` ([Q] typed key array) for
     batch-vs-serial determinism; otherwise ``key`` is split into Q streams.
+    A dict passed as ``info`` receives ``"levels"``: the number of probe
+    levels the step ran, as an int32 device scalar.
     """
-    us = jnp.asarray(us, jnp.int32)
-    q = int(us.shape[0])
-    n_walks = int(n_r or params.n_r)
-    acc = jnp.zeros((q, g.n), jnp.float32)
-    _, est, _, _ = _fused_serve(
-        _query_keys(key, keys, q), g, eg, us, acc,
-        n_r=n_walks,
-        lanes_q=max(1, lanes // q),
-        max_len=params.max_len,
-        sqrt_c=params.sqrt_c,
-        eps_p=params.eps_p,
-        eps_t=params.eps_t,
-        truncation_shift=params.truncation_shift,
-        use_kernel=use_kernel,
-        top_k=0,
-        kernel_dtype=kernel_dtype,
+    est, _, _ = _serve(
+        key, g, eg, us, params, k=0, lanes=lanes, use_kernel=use_kernel,
+        kernel_dtype=kernel_dtype, n_r=n_r, keys=keys, info=info,
     )
     return est
 
@@ -390,27 +410,16 @@ def multi_source_topk(
     kernel_dtype: str = "float32",
     n_r: int | None = None,
     keys: Array | None = None,
+    info: dict | None = None,
 ) -> tuple[Array, Array]:
     """Fused batched top-k (paper Def. 2): (nodes [Q, k], estimates [Q, k]).
 
     The query node itself is excluded; ``top_k`` runs inside the same
-    compiled step as sampling and the probe.
+    compiled step as sampling and the probe.  ``info`` as for
+    :func:`multi_source`.
     """
-    us = jnp.asarray(us, jnp.int32)
-    q = int(us.shape[0])
-    n_walks = int(n_r or params.n_r)
-    acc = jnp.zeros((q, g.n), jnp.float32)
-    _, _, idx, vals = _fused_serve(
-        _query_keys(key, keys, q), g, eg, us, acc,
-        n_r=n_walks,
-        lanes_q=max(1, lanes // q),
-        max_len=params.max_len,
-        sqrt_c=params.sqrt_c,
-        eps_p=params.eps_p,
-        eps_t=params.eps_t,
-        truncation_shift=params.truncation_shift,
-        use_kernel=use_kernel,
-        top_k=int(k),
-        kernel_dtype=kernel_dtype,
+    _, idx, vals = _serve(
+        key, g, eg, us, params, k=k, lanes=lanes, use_kernel=use_kernel,
+        kernel_dtype=kernel_dtype, n_r=n_r, keys=keys, info=info,
     )
     return idx, vals

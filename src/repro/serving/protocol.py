@@ -235,6 +235,8 @@ def envelope_to_wire(env, **extra) -> dict:
         out["certified_bound"] = _jsonable(env.certified_bound)
         out["certificate"] = env.certificate
         out["rounds"] = _jsonable(env.rounds)
+    if env.probe_levels is not None:
+        out["probe_levels"] = int(env.probe_levels)
     out.update({k: _jsonable(v) for k, v in extra.items()})
     return out
 

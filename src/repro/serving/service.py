@@ -65,6 +65,16 @@ from repro.serving.straggler import (
     HedgePolicy,
     dispatch_adaptive,
 )
+from repro.utils.spans import (
+    COLLECTOR_IDLE,
+    COLLECTOR_WINDOW,
+    LOCK_QUERY,
+    LOCK_UPDATE,
+    RESPOND,
+    UPDATE,
+    locked,
+    span,
+)
 
 DEFAULT_TENANT = "default"
 _TENANT_CHARS = frozenset(
@@ -141,7 +151,9 @@ class ServiceStats:
 
     ``batch_hist`` maps micro-batch size -> count of fused dispatches that
     served exactly that many live queries (adaptive-with-deadline requests
-    dispatch individually and land in bucket 1)."""
+    dispatch individually and land in bucket 1).  ``update_lock_wait_s``
+    totals the time updates waited for the graph lock, which a query
+    dispatch in flight holds."""
 
     accepted: int = 0
     served: int = 0
@@ -150,6 +162,7 @@ class ServiceStats:
     errors_5xx: int = 0
     batches: int = 0
     updates_applied: int = 0
+    update_lock_wait_s: float = 0.0
     batch_hist: dict[int, int] = field(default_factory=dict)
 
     def as_dict(self) -> dict:
@@ -441,18 +454,22 @@ class SimRankService:
                 while not self._pending:
                     if self._closed:
                         return
-                    self._cond.wait(timeout=0.25)
+                    # one span per wait: a span open when a trace starts
+                    # or stops is not recorded
+                    with span(COLLECTOR_IDLE):
+                        self._cond.wait(timeout=0.25)
                 # the first pending request armed the window; cut at the
                 # timer or as soon as a full batch is waiting
                 cut_at = self._pending[0].t_enq + window_s
-                while (
-                    len(self._pending) < self.config.max_batch_q
-                    and not self._closed
-                ):
-                    rem = cut_at - time.monotonic()
-                    if rem <= 0:
-                        break
-                    self._cond.wait(timeout=rem)
+                with span(COLLECTOR_WINDOW):
+                    while (
+                        len(self._pending) < self.config.max_batch_q
+                        and not self._closed
+                    ):
+                        rem = cut_at - time.monotonic()
+                        if rem <= 0:
+                            break
+                        self._cond.wait(timeout=rem)
                 batch = self._cut_window()
             try:
                 self._serve_cut(batch)
@@ -538,7 +555,7 @@ class SimRankService:
         t0 = time.monotonic()
         try:
             sess = self.session(tenant)
-            with self._graph_lock:
+            with locked(self._graph_lock, LOCK_QUERY):
                 tickets = [sess.submit(it.spec) for it in items]
                 sess.drain()
         except Exception as e:
@@ -552,13 +569,14 @@ class SimRankService:
             self.stats.batch_hist.get(len(items), 0) + 1
         )
         self.stats.served += len(items)
-        for it, tk in zip(items, tickets):
-            self._finish(it, 200, envelope_to_wire(
-                tk.envelope,
-                tenant=tenant,
-                batch_size=len(items),
-                queue_delay_s=t0 - it.t_enq,
-            ))
+        with span(RESPOND):
+            for it, tk in zip(items, tickets):
+                self._finish(it, 200, envelope_to_wire(
+                    tk.envelope,
+                    tenant=tenant,
+                    batch_size=len(items),
+                    queue_delay_s=t0 - it.t_enq,
+                ))
 
     def _serve_adaptive_solo(self, it: _PendingQuery) -> None:
         """Adaptive + deadline: in-band clamp via dispatch_adaptive."""
@@ -568,7 +586,7 @@ class SimRankService:
         )
         try:
             sess = self.session(it.tenant)
-            with self._graph_lock:
+            with locked(self._graph_lock, LOCK_QUERY):
                 env = dispatch_adaptive(
                     sess.query, it.spec,
                     policy=HedgePolicy(deadline_s=rem),
@@ -590,12 +608,13 @@ class SimRankService:
         self.stats.batches += 1
         self.stats.batch_hist[1] = self.stats.batch_hist.get(1, 0) + 1
         self.stats.served += 1
-        self._finish(it, 200, envelope_to_wire(
-            env,
-            tenant=it.tenant,
-            batch_size=1,
-            queue_delay_s=t0 - it.t_enq,
-        ))
+        with span(RESPOND):
+            self._finish(it, 200, envelope_to_wire(
+                env,
+                tenant=it.tenant,
+                batch_size=1,
+                queue_delay_s=t0 - it.t_enq,
+            ))
 
     # -- updates -------------------------------------------------------------
 
@@ -612,17 +631,20 @@ class SimRankService:
         against a consistent pre- or post-update snapshot, and the bumped
         ``version`` in its envelope says which.  All tenants share the
         graph state, so they all observe the new version immediately.
+        The wait for the lock adds to ``stats.update_lock_wait_s``.
         """
-        with self._graph_lock:
+        with locked(self._graph_lock, LOCK_UPDATE) as waited:
+            self.stats.update_lock_wait_s += waited
             sess = self.session(DEFAULT_TENANT)
-            rep = sess.update(
-                inserts=None if inserts is None else (
-                    inserts[:, 0], inserts[:, 1]
-                ),
-                deletes=None if deletes is None else (
-                    deletes[:, 0], deletes[:, 1]
-                ),
-            )
+            with span(UPDATE):
+                rep = sess.update(
+                    inserts=None if inserts is None else (
+                        inserts[:, 0], inserts[:, 1]
+                    ),
+                    deletes=None if deletes is None else (
+                        deletes[:, 0], deletes[:, 1]
+                    ),
+                )
             self.stats.updates_applied += rep.applied
         return update_report_to_wire(rep, n=self.n)
 

@@ -163,8 +163,8 @@ def test_sharded_update_then_query_equals_rebuild(handle):
         params=p, walk_chunk=128,
     )
     k = jnp.stack([jax.random.key(11)])
-    a, _, _ = be.serve_batch("single_source", [3], k, n_r=192)
-    b, _, _ = rebuilt.serve_batch("single_source", [3], k, n_r=192)
+    a, _, _, _ = be.serve_batch("single_source", [3], k, n_r=192)
+    b, _, _, _ = rebuilt.serve_batch("single_source", [3], k, n_r=192)
     np.testing.assert_array_equal(a, b)  # exact, not tolerance
 
 
@@ -284,7 +284,7 @@ def test_sharded_odd_edge_chunks_pad_cleanly(handle):
     p = make_params(handle.n, c=0.6, eps_a=0.2, delta=0.01)
     be = ShardedBackend(handle.shard(shards=1), params=p,
                         walk_chunk=64, edge_chunks=3)
-    est, _, _ = be.serve_batch(
+    est, _, _, _ = be.serve_batch(
         "single_source", [3], jnp.stack([jax.random.key(0)]), n_r=64
     )
     assert est.shape == (1, handle.n)
@@ -474,9 +474,9 @@ def test_sharded_batched_scores_match_per_query(handle):
                              walk_chunk=wq * len(nodes))
     single = ShardedBackend(handle.shard(shards=1), params=p, walk_chunk=wq)
     keys = jnp.stack([jax.random.key(40 + u) for u in nodes])
-    est_b, _, _ = batched.serve_batch("single_source", nodes, keys, n_r=96)
+    est_b, _, _, _ = batched.serve_batch("single_source", nodes, keys, n_r=96)
     for i, u in enumerate(nodes):
-        est_1, _, _ = single.serve_batch(
+        est_1, _, _, _ = single.serve_batch(
             "single_source", [u], keys[i:i + 1], n_r=96
         )
         assert np.abs(est_b[i] - est_1[0]).max() < 1e-6, u
@@ -583,8 +583,8 @@ assert shard.backend.batch_dispatch_label(3) == "sharded[spmd,Q=3]"
 assert ring.backend.batch_dispatch_label(3) == "sharded[ring,Q=3]"
 ub = [nodes[0], nodes[1], nodes[0]]
 kb = jnp.stack([jax.random.key(200 + i) for i in range(3)])
-ba, _, _ = shard.backend.serve_batch("single_source", ub, kb, n_r=512)
-bb, _, _ = ring.backend.serve_batch("single_source", ub, kb, n_r=512)
+ba, _, _, _ = shard.backend.serve_batch("single_source", ub, kb, n_r=512)
+bb, _, _, _ = ring.backend.serve_batch("single_source", ub, kb, n_r=512)
 assert np.abs(ba - bb).max() < 1e-4, np.abs(ba - bb).max()
 print("RING_SPMD_BATCH_OK")
 
@@ -599,8 +599,8 @@ reb = ShardedBackend(ShardedGraphState(s2, d2, n, shards=4,
                                        version=shard.version),
                      params=shard.params, walk_chunk=512)
 k = jnp.stack([jax.random.key(7)])
-a, _, _ = shard.backend.serve_batch("single_source", [nodes[0]], k, n_r=512)
-b, _, _ = reb.serve_batch("single_source", [nodes[0]], k, n_r=512)
+a, _, _, _ = shard.backend.serve_batch("single_source", [nodes[0]], k, n_r=512)
+b, _, _, _ = reb.serve_batch("single_source", [nodes[0]], k, n_r=512)
 assert np.array_equal(a, b)
 
 # ring probe with a non-divisible column count: budget 65 at walk_chunk 64

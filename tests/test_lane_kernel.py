@@ -265,15 +265,15 @@ kb = jnp.stack([jax.random.key(200 + i) for i in range(3)])
 sh_x = ShardedBackend(h.shard(shards=4), params=p, walk_chunk=512)
 sh_k = ShardedBackend(h.shard(shards=4), params=p, walk_chunk=512,
                       use_kernel=True)
-a, _, _ = sh_x.serve_batch("single_source", nodes, kb, n_r=512)
-b, _, _ = sh_k.serve_batch("single_source", nodes, kb, n_r=512)
+a, _, _, _ = sh_x.serve_batch("single_source", nodes, kb, n_r=512)
+b, _, _, _ = sh_k.serve_batch("single_source", nodes, kb, n_r=512)
 assert np.abs(a - b).max() < 1e-4, np.abs(a - b).max()
 
 # bf16 frontier exchange (kernel + XLA paths) vs fp32 wire
 for uk in (True, False):
     bf = ShardedBackend(h.shard(shards=4), params=p, walk_chunk=512,
                         use_kernel=uk, frontier_dtype="bfloat16")
-    c, _, _ = bf.serve_batch("single_source", nodes, kb, n_r=512)
+    c, _, _, _ = bf.serve_batch("single_source", nodes, kb, n_r=512)
     ref = b if uk else a
     assert np.abs(ref - c).max() < 1e-3, (uk, np.abs(ref - c).max())
 print("SPMD_KERNEL_OK")
@@ -284,14 +284,14 @@ ring_x = ShardedBackend(h.shard(shards=4), params=p, walk_chunk=512,
                         probe="ring")
 ring_k = ShardedBackend(h.shard(shards=4), params=p, walk_chunk=512,
                         probe="ring", use_kernel=True)
-e, _, _ = ring_x.serve_batch("single_source", nodes, kb, n_r=512)
-f, _, _ = ring_k.serve_batch("single_source", nodes, kb, n_r=512)
+e, _, _, _ = ring_x.serve_batch("single_source", nodes, kb, n_r=512)
+f, _, _, _ = ring_k.serve_batch("single_source", nodes, kb, n_r=512)
 assert np.array_equal(e, f), np.abs(e - f).max()
 print("RING_KERNEL_OK")
 
 # top-k rides the same probe
-_, ix, vx = sh_x.serve_batch("topk", nodes, kb, k=5, n_r=512)
-_, ik, vk = sh_k.serve_batch("topk", nodes, kb, k=5, n_r=512)
+_, ix, vx, _ = sh_x.serve_batch("topk", nodes, kb, k=5, n_r=512)
+_, ik, vk, _ = sh_k.serve_batch("topk", nodes, kb, k=5, n_r=512)
 assert all(len(set(ix[i].tolist()) & set(ik[i].tolist())) >= 4
            for i in range(3))
 
