@@ -121,6 +121,9 @@ class Backend(Protocol):
     name: str
     supports_epoch: bool
     variants: tuple[str, ...]
+    # the push the latest fused serve dispatch's probe levels ran
+    # (``core.multisource.push_path``), or None where it is not reported
+    push_path: str | None
 
     @property
     def n(self) -> int: ...
@@ -193,6 +196,7 @@ class LocalBackend:
     name = "local"
     supports_epoch = True
     variants = ("auto", "telescoped", "tree", "reference", "randomized")
+    push_path: str | None = None
 
     def __init__(
         self,
@@ -287,7 +291,8 @@ class LocalBackend:
     ) -> tuple:
         """One fused multi-query dispatch; returns ``(est, idx, vals,
         levels)`` (est for single_source kind, idx/vals for topk — the
-        unused side is None; ``levels`` is the probe levels the step ran).
+        unused side is None; ``levels`` is the probe levels the step ran)
+        and sets ``push_path``.
         Exactly one of ``keys`` ([Q] per-query streams) / ``key`` (scalar:
         legacy split semantics) is set."""
         g, eg = self.handle.g, self.handle.eg
@@ -302,10 +307,12 @@ class LocalBackend:
             idx, vals = multi_source_topk(
                 key, g, eg, us, k, self.params, **common
             )
+            self.push_path = info["push_path"]
             with span(DISPATCH_FETCH):  # one transfer, answers and count
                 idx, vals, levels = jax.device_get((idx, vals, info["levels"]))
             return None, idx, vals, int(levels)
         est = multi_source(key, g, eg, us, self.params, **common)
+        self.push_path = info["push_path"]
         with span(DISPATCH_FETCH):
             est, levels = jax.device_get((est, info["levels"]))
         return est, None, None, int(levels)
@@ -734,6 +741,7 @@ class ShardedBackend:
     """
 
     name = "sharded"
+    push_path = None  # the mesh probes keep their own pushes
     supports_epoch = True
     variants = ("auto", "telescoped")
 

@@ -98,7 +98,9 @@ class EngineStats:
     (extra dispatches adaptive queries paid), ``hub_hits`` whole serve
     dispatches skipped because every row of an escalation round was
     already in the hub probe cache.  ``probe_levels`` totals the probe
-    levels of the fused serve dispatches whose backend counts them.
+    levels of the fused serve dispatches whose backend counts them;
+    ``push_path`` names the push the latest of them ran
+    (``core.multisource.push_path``).
     """
 
     queries: int = 0
@@ -110,6 +112,7 @@ class EngineStats:
     escalations: int = 0
     hub_hits: int = 0
     probe_levels: int = 0
+    push_path: str | None = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -548,6 +551,7 @@ class SimRankSession:
         self.stats.steps += 1
         self.stats.queries += spec.q
         self.stats.probe_levels += levels or 0
+        path = self._note_push_path(levels)
         return ResultEnvelope(
             kind=spec.kind,
             node=spec.node,
@@ -558,8 +562,19 @@ class SimRankSession:
             error_bound=self.error_bound(n_r),
             variant=self.backend.dispatch_label(variant),
             probe_levels=levels,
+            push_path=path,
             **out,
         )
+
+    def _note_push_path(self, levels) -> str | None:
+        """The push of the fused dispatch that just ran, kept in
+        ``stats.push_path``; None where the dispatch counted no levels
+        (one-shot variants, the mesh backend)."""
+        if levels is None:
+            return None
+        path = getattr(self.backend, "push_path", None)
+        self.stats.push_path = path
+        return path
 
     def _multi_keys(self, spec: QuerySpec):
         """(key, keys) for a batched spec — exactly one of the two is set."""
@@ -629,6 +644,7 @@ class SimRankSession:
             certificate=worst.certificate,
             rounds=max(e.rounds for e in envs),
             probe_levels=envs[0].probe_levels,
+            push_path=envs[0].push_path,
         )
 
     def _serve_adaptive(
@@ -689,6 +705,7 @@ class SimRankSession:
                 cacheable.append(False)
         ver = self.version
         levels = None  # summed over the rounds that dispatched
+        path = None
         t0 = time.perf_counter()
         while True:
             n_round = ctrl.next_round()
@@ -730,6 +747,7 @@ class SimRankSession:
                 if lv is not None:
                     levels = (levels or 0) + lv
                     self.stats.probe_levels += lv
+                path = self._note_push_path(lv) or path
                 self.stats.steps += 1
                 if r > 0:
                     self.stats.escalations += 1
@@ -758,6 +776,7 @@ class SimRankSession:
                 certificate=cert.name,
                 rounds=cert.rounds,
                 probe_levels=levels,
+                push_path=path,
             )
             if sp.kind == "single_source":
                 env.scores = scores
@@ -853,6 +872,7 @@ class SimRankSession:
             )
         self.stats.steps += 1
         self.stats.probe_levels += levels or 0
+        path = self._note_push_path(levels)
         ver = self.version
         bound = self.error_bound(n_r)
         return [
@@ -868,6 +888,7 @@ class SimRankSession:
                 error_bound=bound,
                 variant=self.backend.dispatch_label("telescoped"),
                 probe_levels=levels,
+                push_path=path,
             )
             for i, item in enumerate(batch)
         ]

@@ -123,7 +123,9 @@ class ResultEnvelope:
     dispatch ran (summed over the rounds of an adaptive query; every
     answer of one dispatch carries the same count), or None where the
     path does not count them (one-shot legacy variants, fused epochs,
-    the sharded backend).
+    the sharded backend).  ``push_path`` names the push those levels ran
+    (``core.multisource.push_path``: ``csr_kernel``, ``coo_xla``, ...),
+    None where ``probe_levels`` is.
 
     Field-superset of the legacy ``QueryResult`` — engine shims return
     envelopes directly.
@@ -145,3 +147,4 @@ class ResultEnvelope:
     certificate: str | None = None
     rounds: int = 1
     probe_levels: int | None = None
+    push_path: str | None = None

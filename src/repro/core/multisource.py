@@ -150,6 +150,38 @@ def lane_thresholds(pos, *, sqrt_c: float, eps_p: float):
     )
 
 
+def push_path(g: Graph | EllGraph, width: int, *, use_kernel: bool) -> str:
+    """The push a fused serve step's probe levels run, from what the step
+    can observe: ``"ell_kernel"`` under ``use_kernel``; else, over a COO
+    push graph, ``"csr_kernel"`` where JAX runs on a TPU and the fp32
+    frontier of ``width`` lane columns fits the CSR kernel's on-chip
+    budget, and ``"coo_xla"`` otherwise (an ELL push graph: ``"ell_xla"``).
+    """
+    if use_kernel:
+        return "ell_kernel"
+    if isinstance(g, EllGraph):
+        return "ell_xla"
+    from repro.kernels.lane_probe.ops import csr_level_fits
+
+    if jax.default_backend() == "tpu" and csr_level_fits(g.n, width):
+        return "csr_kernel"
+    return "coo_xla"
+
+
+def xla_level(g, scores, total, fin, u_p, u_prev, thr, *, cols, sqrt_c,
+              prune):
+    """One lane-probe level in XLA ops on an [n + 1, W] buffer: deposit,
+    inject, prune, push (``push_level_padded``), exclude."""
+    total = total + jnp.where(fin[None, :], scores, 0.0)
+    scores = jnp.where(fin[None, :], 0.0, scores)
+    scores = scores.at[u_p, cols].add(1.0)  # sentinel -> dump row
+    if prune:
+        scores = jnp.where(scores > thr[None, :], scores, 0.0)
+    scores = push_level_padded(g, scores, sqrt_c, use_kernel=False)
+    scores = scores.at[u_prev, cols].set(0.0)  # exclusion mask
+    return scores, total
+
+
 def fused_serve_impl(
     keys: Array,  # [Q] typed PRNG keys, one stream per query
     g: Graph | EllGraph,
@@ -173,7 +205,11 @@ def fused_serve_impl(
     ``use_kernel=True`` runs each probe level through the fused Pallas
     lane-probe kernel (``kernels/lane_probe``) against the ELL push table;
     ``kernel_dtype="bfloat16"`` additionally stores the score/accumulator
-    buffers in bf16 (accumulation stays fp32 on-chip).  Returns
+    buffers in bf16 (accumulation stays fp32 on-chip).  Otherwise
+    :func:`push_path` picks the level: on a TPU, when the frontier fits,
+    the fused CSR kernel over a dst-sorted view of the COO graph, built
+    once here (sums in CSR order: 1e-5 of the XLA level); else the XLA
+    level.  Returns
     ``(acc, est, topk_idx, topk_vals, levels)``; the top-k outputs are
     None when ``top_k == 0``.  ``levels`` (int32 scalar) counts the probe
     levels the step ran: the peeled first level plus the loop's trips.
@@ -205,7 +241,9 @@ def fused_serve_impl(
     head = walks_of(us, cont[:, :h], pick[:, :h])  # [Q, h, max_len]
 
     # --- one probe level: deposit + inject + prune + push + exclude -------
-    if use_kernel:
+    path = push_path(g, w, use_kernel=use_kernel)
+    rows = n + 1  # score buffer rows: the graph's and the dump row
+    if path == "ell_kernel":
         from repro.kernels.lane_probe.ops import lane_probe_level
 
         ell = g if isinstance(g, EllGraph) else eg
@@ -222,17 +260,24 @@ def fused_serve_impl(
                 jnp.concatenate([out, zrow]),
                 jnp.concatenate([tot, zrow]),
             )
-    else:
+    elif path == "csr_kernel":
+        from repro.kernels.lane_probe.ops import (
+            csr_layout, csr_push_view, lane_probe_csr_level,
+        )
+
+        # the CSR view of the graph version this step serves, built once
+        rows, _ = csr_layout(n)
+        view = csr_push_view(g, g.inv_in_deg * sqrt_c, rows=rows)
 
         def level_fn(scores, total, fin, u_p, u_prev, thr):
-            total = total + jnp.where(fin[None, :], scores, 0.0)
-            scores = jnp.where(fin[None, :], 0.0, scores)
-            scores = scores.at[u_p, cols].add(1.0)  # sentinel -> dump row
-            if eps_p > 0.0:
-                scores = jnp.where(scores > thr[None, :], scores, 0.0)
-            scores = push_level_padded(g, scores, sqrt_c, use_kernel=False)
-            scores = scores.at[u_prev, cols].set(0.0)  # exclusion mask
-            return scores, total
+            return lane_probe_csr_level(
+                view, scores, total, fin, u_p, u_prev, thr,
+                prune=eps_p > 0.0,
+            )
+    else:
+        level_fn = partial(
+            xla_level, g, cols=cols, sqrt_c=sqrt_c, prune=eps_p > 0.0
+        )
 
     # --- compacted probe loop ---------------------------------------------
     # Per-column state: pos (current walk position; 1/0 = finished/idle),
@@ -262,8 +307,8 @@ def fused_serve_impl(
         jnp.zeros(w, jnp.int32),  # pos: all idle -> first iteration refills
         jnp.zeros(w, jnp.int32),  # widx
         jnp.zeros(q, jnp.int32),  # next_q
-        jnp.zeros((n + 1, w), dtype),  # scores (baked dump row)
-        jnp.zeros((n + 1, w), dtype),  # total (baked dump row)
+        jnp.zeros((rows, w), dtype),  # scores (baked dump row)
+        jnp.zeros((rows, w), dtype),  # total (baked dump row)
     )
     # First level runs against the head-only pool (the first refill can only
     # claim head walks, so this is bit-identical to the full-pool level);
@@ -340,14 +385,16 @@ def _serve(
     """One jitted fused step for :func:`multi_source` (``k == 0``) and
     :func:`multi_source_topk`; returns ``(est, idx, vals)`` on the device
     and puts the step's probe-level count (int32 scalar) in
-    ``info["levels"]`` when ``info`` is a dict."""
+    ``info["levels"]`` and its :func:`push_path` in ``info["push_path"]``
+    when ``info`` is a dict."""
     us = jnp.asarray(us, jnp.int32)
     q = int(us.shape[0])
+    lanes_q = max(1, lanes // q)
     acc = jnp.zeros((q, g.n), jnp.float32)
     _, est, idx, vals, levels = _fused_serve(
         _query_keys(key, keys, q), g, eg, us, acc,
         n_r=int(n_r or params.n_r),
-        lanes_q=max(1, lanes // q),
+        lanes_q=lanes_q,
         max_len=params.max_len,
         sqrt_c=params.sqrt_c,
         eps_p=params.eps_p,
@@ -359,6 +406,7 @@ def _serve(
     )
     if info is not None:
         info["levels"] = levels
+        info["push_path"] = push_path(g, q * lanes_q, use_kernel=use_kernel)
     return est, idx, vals
 
 
@@ -388,7 +436,8 @@ def multi_source(
     serving).  Pass per-query ``keys`` ([Q] typed key array) for
     batch-vs-serial determinism; otherwise ``key`` is split into Q streams.
     A dict passed as ``info`` receives ``"levels"``: the number of probe
-    levels the step ran, as an int32 device scalar.
+    levels the step ran, as an int32 device scalar, and ``"push_path"``:
+    the push its levels ran (:func:`push_path`).
     """
     est, _, _ = _serve(
         key, g, eg, us, params, k=0, lanes=lanes, use_kernel=use_kernel,

@@ -237,6 +237,8 @@ def envelope_to_wire(env, **extra) -> dict:
         out["rounds"] = _jsonable(env.rounds)
     if env.probe_levels is not None:
         out["probe_levels"] = int(env.probe_levels)
+    if env.push_path is not None:
+        out["push_path"] = env.push_path
     out.update({k: _jsonable(v) for k, v in extra.items()})
     return out
 

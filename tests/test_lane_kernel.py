@@ -334,3 +334,134 @@ def test_sharded_kernel_parity_on_fake_mesh():
     assert "SPMD_KERNEL_OK" in out.stdout
     assert "RING_KERNEL_OK" in out.stdout
     assert "EPOCH_KERNEL_OK" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# CSR level (the default path's push on the TPU) vs the XLA COO level
+# ---------------------------------------------------------------------------
+
+
+def _csr_case(rng, case):
+    """A COO graph and one level's lane state for a CSR parity case."""
+    from repro.graph.structs import graph_from_edges
+
+    n, m, w = 90, 600, 40
+    src = rng.integers(0, n, m)
+    dst = rng.integers(0, n // 2, m)  # rows n/2 .. n-1 take no in-edges
+    cap = m
+    if case == "padding":
+        cap = m + 77  # padding edges (dst = n) sort past the live rows
+    if case == "hub":
+        dst[:300] = 5  # a row longer than two id chunks of 128
+    g = graph_from_edges(src, dst, n, capacity=cap)
+    scores = rng.random((n + 1, w)) * (rng.random((n + 1, w)) < 0.4)
+    scores[n] = 0.0
+    total = rng.random((n + 1, w))
+    total[n] = 0.0
+    fin = rng.random(w) < 0.3
+    u_p = np.where(rng.random(w) < 0.6, rng.integers(0, n, w), n)
+    u_prev = np.where(rng.random(w) < 0.6, rng.integers(0, n, w), n)
+    if case == "dead_fin":
+        fin[: w // 2] = True  # depositing columns; half of them dead
+        u_p[: w // 4] = n
+        scores[:, w // 4: w // 2] = 0.0
+    if case == "sentinels":
+        u_p[:] = n
+        u_prev[:] = n
+    thr = rng.random(w) * 0.1
+    f32, i32 = jnp.float32, jnp.int32
+    return g, (jnp.asarray(scores, f32), jnp.asarray(total, f32),
+               jnp.asarray(fin), jnp.asarray(u_p, i32),
+               jnp.asarray(u_prev, i32), jnp.asarray(thr, f32))
+
+
+@pytest.mark.parametrize("prune", [False, True])
+@pytest.mark.parametrize(
+    "case", ["padding", "hub", "empty_rows", "dead_fin", "sentinels"]
+)
+def test_csr_level_matches_coo_level(rng, case, prune):
+    """The CSR kernel (interpret mode) against the XLA COO level at 1e-5:
+    padding edges, a hub row over several id chunks, rows with no
+    in-edges, dead and depositing columns, sentinel u_p/u_prev."""
+    from repro.core.multisource import xla_level
+    from repro.kernels.lane_probe.ops import (
+        csr_layout, csr_push_view, lane_probe_csr_level,
+    )
+
+    g, (scores, total, fin, u_p, u_prev, thr) = _csr_case(rng, case)
+    n, w = g.n, scores.shape[1]
+    sqrt_c = 0.6 ** 0.5
+    ref_s, ref_t = xla_level(
+        g, scores, total, fin, u_p, u_prev, thr,
+        cols=jnp.arange(w), sqrt_c=sqrt_c, prune=prune,
+    )
+    rows, _ = csr_layout(n, block_rows=64)
+    view = csr_push_view(g, g.inv_in_deg * sqrt_c, rows=rows, chunk=128)
+    grow = ((0, rows - n - 1), (0, 0))
+    out, tot = lane_probe_csr_level(
+        view, jnp.pad(scores, grow), jnp.pad(total, grow),
+        fin, u_p, u_prev, thr, prune=prune, block_rows=64, chunk=128,
+    )
+    out, tot = np.asarray(out), np.asarray(tot)
+    np.testing.assert_allclose(out[: n + 1], ref_s, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(tot[: n + 1], ref_t)
+    assert np.all(out[n:] == 0.0)  # the dump row and block padding
+    assert np.abs(out).sum() > 0  # a non-degenerate level
+
+
+def _serve_and_epoch(d, key):
+    """(serve before, epoch, serve after) estimates on small_powerlaw and
+    the serves' push paths: the epoch inserts into spare COO slots and
+    deletes live edges."""
+    from repro.api import GraphHandle, LocalBackend
+    from repro.core import make_params
+    from repro.graph.dynamic import make_update_batch
+
+    p = make_params(d["n"], c=0.6, eps_a=0.2, delta=0.01)
+    h = GraphHandle.from_edges(d["src"], d["dst"], d["n"],
+                               capacity=len(d["src"]) + 64)
+    be = LocalBackend(h, params=p, walk_chunk=128)
+    keys = jax.random.split(key, 2)
+    us = [int(u) for u in np.argsort(-np.bincount(d["dst"]))[:2]]
+    before, _, _, _ = be.serve_batch("single_source", us, keys, n_r=128)
+    paths = [be.push_path]
+    rng = np.random.default_rng(7)
+    dels = rng.choice(len(d["src"]), 12, replace=False)
+    src = np.concatenate([rng.integers(0, d["n"], 12), d["src"][dels]])
+    dst = np.concatenate([rng.integers(0, d["n"], 12), d["dst"][dels]])
+    batch = make_update_batch(src, dst, np.arange(24) < 12, batch_size=32,
+                              n=d["n"])
+    applied, epoch, _, _ = be.epoch_batch(batch, us, keys, n_r=128,
+                                          top_k=0)
+    assert np.asarray(applied)[12:24].all()  # every delete found its edge
+    after, _, _, _ = be.serve_batch("single_source", us, keys, n_r=128)
+    paths.append(be.push_path)
+    return [before, epoch, after], paths
+
+
+def test_csr_serve_and_epoch_match_xla(small_powerlaw, key, monkeypatch):
+    """The fused serve and epoch steps on the CSR path (steered onto it,
+    as on a TPU; interpret mode here) match the XLA path at 1e-5, before
+    and after an epoch that inserts and deletes: each dispatch's CSR view
+    follows the graph version it serves."""
+    from repro.core import multisource
+
+    real = multisource.push_path
+
+    def csr(g, width, *, use_kernel):
+        path = real(g, width, use_kernel=use_kernel)
+        return "csr_kernel" if path == "coo_xla" else path
+
+    jax.clear_caches()  # compiled steps carry the path they traced
+    try:
+        xla, xla_paths = _serve_and_epoch(small_powerlaw, key)
+        monkeypatch.setattr(multisource, "push_path", csr)
+        jax.clear_caches()
+        got, got_paths = _serve_and_epoch(small_powerlaw, key)
+    finally:
+        jax.clear_caches()
+    assert xla_paths == ["coo_xla"] * 2  # the CPU's default
+    assert got_paths == ["csr_kernel"] * 2
+    assert not np.array_equal(xla[0], xla[2])  # the update moved answers
+    for a, b in zip(xla, got):
+        np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)

@@ -134,6 +134,27 @@ def test_adaptive_levels_sum_over_rounds(handle):
     assert env.probe_levels == sess.stats.probe_levels > 0
 
 
+def test_push_path_rides_with_the_levels(handle):
+    """Each fused answer names the push its levels ran (the XLA COO level
+    off the TPU), in the envelope, its wire form and the session stats; a
+    one-shot legacy query counts no levels and names none."""
+    from repro.serving.protocol import envelope_to_wire
+
+    sess = SimRankSession(handle, batch_q=2, eps_a=0.2)
+    assert sess.stats.push_path is None
+    sess.submit(QuerySpec(kind="topk", node=1, k=5, budget_walks=64))
+    (env,) = sess.drain()
+    assert env.push_path == sess.stats.push_path == "coo_xla"
+    assert envelope_to_wire(env)["push_path"] == "coo_xla"
+    adaptive = sess.query(QuerySpec(kind="topk", node=2, k=5, epsilon=0.5,
+                                    budget_walks=64))
+    assert adaptive.push_path == "coo_xla"
+    one = sess.query(QuerySpec(kind="topk", node=3, k=5, budget_walks=64))
+    assert one.probe_levels is None and one.push_path is None
+    assert "push_path" not in envelope_to_wire(one)
+    assert sess.stats.push_path == "coo_xla"
+
+
 @pytest.mark.parametrize("top_k", [0, 5])
 def test_counter_leaves_answers_bitwise_unchanged(handle, top_k):
     """The step with its level count against the same step compiled

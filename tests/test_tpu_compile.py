@@ -145,3 +145,64 @@ def test_sharded_serve_step_compiles_on_four_chips(topo):
     ).compile()
     hlo = compiled.as_text()
     assert "all-gather" in hlo
+
+
+# the benchmark's realized graphs (bench/configs): cit-hepph, and
+# wiki-vote at its COO capacity (m + 1,024 spare slots)
+BENCH_GRAPHS = {"hepph": (34_546, 421_578), "wiki": (7_115, 104_713)}
+
+
+def _coo_shapes(n, cap, sharding):
+    from repro.graph.structs import Graph
+
+    s = lambda shape, dt=jnp.int32: _sds(shape, dt, sharding)  # noqa: E731
+    return Graph(src=s((cap,)), dst=s((cap,)), in_deg=s((n,)),
+                 out_deg=s((n,)), num_edges=s(()), n=n, capacity=cap,
+                 version=s(()), overflow=s((), jnp.bool_))
+
+
+@pytest.mark.parametrize("cell", sorted(BENCH_GRAPHS))
+def test_csr_level_lowers_at_bench_shape(one_chip, cell):
+    """The CSR level compiles for the chip at the benchmark's realized
+    shapes (W = 256), over the view ``csr_push_view`` gives the graph."""
+    from repro.kernels.lane_probe.lane_probe import CSR_CHUNK
+    from repro.kernels.lane_probe.ops import (
+        csr_layout, csr_level_fits, lane_probe_csr_level,
+    )
+
+    n, cap = BENCH_GRAPHS[cell]
+    W = 256
+    assert csr_level_fits(n, W)
+    rows, _ = csr_layout(n)
+    ids = -(-cap // CSR_CHUNK) * CSR_CHUNK
+    i32, f32 = jnp.int32, jnp.float32
+    view = (_sds((rows + 1,), i32, one_chip), _sds((ids,), i32, one_chip),
+            _sds((rows, 1), f32, one_chip))
+    compiled = jax.jit(
+        lambda view, *a: lane_probe_csr_level(view, *a, prune=True,
+                                              interpret=False)
+    ).lower(
+        view, _sds((rows, W), f32, one_chip), _sds((rows, W), f32, one_chip),
+        _sds((W,), jnp.bool_, one_chip),
+        *(_sds((W,), i32, one_chip),) * 2, _sds((W,), f32, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_push_path_follows_the_frontier(monkeypatch):
+    """On a TPU the default path takes the CSR kernel where the fp32
+    frontier fits VMEM (both benchmark graphs) and keeps the XLA COO level
+    where it does not (LiveJournal's 4.8M rows); use_kernel keeps the ELL
+    kernel; off the TPU the XLA COO level."""
+    from repro.core.multisource import push_path
+
+    cpu = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
+    lj = _coo_shapes(4_847_571, 68_993_773, cpu)
+    hepph = _coo_shapes(*BENCH_GRAPHS["hepph"], cpu)
+    assert push_path(hepph, 256, use_kernel=False) == "coo_xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert push_path(hepph, 256, use_kernel=False) == "csr_kernel"
+    wiki = _coo_shapes(*BENCH_GRAPHS["wiki"], cpu)
+    assert push_path(wiki, 256, use_kernel=False) == "csr_kernel"
+    assert push_path(lj, 256, use_kernel=False) == "coo_xla"
+    assert push_path(hepph, 256, use_kernel=True) == "ell_kernel"
