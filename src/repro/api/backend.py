@@ -101,7 +101,10 @@ class Backend(Protocol):
     required query entry point (``serve_one`` has a default route through
     it on both shipped backends) and returns ``(est, idx, vals,
     levels)`` on the host, ``levels`` the probe levels the dispatch ran
-    or None where the backend does not count them; updates arrive as
+    or None where the backend does not count them; its ``live`` says how
+    many leading slots are real queries (the rest repeat-pad the batch),
+    which a backend may use to give the live queries every lane column;
+    updates arrive as
     homogeneous sub-batches (one ``insert`` flag per call, duplicate
     delete pairs already split by the session) and return a per-op
     applied mask with ``GraphHandle.apply_batch`` semantics: an
@@ -124,6 +127,8 @@ class Backend(Protocol):
     # the push the latest fused serve dispatch's probe levels ran
     # (``core.multisource.push_path``), or None where it is not reported
     push_path: str | None
+    # the lane columns each slot of that dispatch owned, or None
+    lanes: list[int] | None
 
     @property
     def n(self) -> int: ...
@@ -149,7 +154,8 @@ class Backend(Protocol):
     ) -> dict: ...
 
     def serve_batch(
-        self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int
+        self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int,
+        live: int | None = None,
     ) -> tuple: ...
 
     def apply_ops(
@@ -197,6 +203,7 @@ class LocalBackend:
     supports_epoch = True
     variants = ("auto", "telescoped", "tree", "reference", "randomized")
     push_path: str | None = None
+    lanes: list[int] | None = None
 
     def __init__(
         self,
@@ -287,34 +294,41 @@ class LocalBackend:
         return dict(topk_nodes=np.asarray(idx), topk_scores=np.asarray(vals))
 
     def serve_batch(
-        self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int
+        self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int,
+        live: int | None = None,
     ) -> tuple:
         """One fused multi-query dispatch; returns ``(est, idx, vals,
         levels)`` (est for single_source kind, idx/vals for topk — the
         unused side is None; ``levels`` is the probe levels the step ran)
-        and sets ``push_path``.
+        and sets ``push_path`` and ``lanes``.
         Exactly one of ``keys`` ([Q] per-query streams) / ``key`` (scalar:
-        legacy split semantics) is set."""
+        legacy split semantics) is set.  ``live`` (None: every slot)
+        leading slots are real queries and own all the lane columns; the
+        padding slots' rows carry no estimate."""
         g, eg = self.handle.g, self.handle.eg
         us = jnp.asarray(us, jnp.int32)
         info: dict = {}
         common = dict(
             lanes=self.walk_chunk, n_r=n_r, keys=keys,
             use_kernel=self.use_kernel, kernel_dtype=self.kernel_dtype,
-            info=info,
+            info=info, live=live,
         )
         if kind == "topk":
             idx, vals = multi_source_topk(
                 key, g, eg, us, k, self.params, **common
             )
-            self.push_path = info["push_path"]
-            with span(DISPATCH_FETCH):  # one transfer, answers and count
-                idx, vals, levels = jax.device_get((idx, vals, info["levels"]))
+            with span(DISPATCH_FETCH):  # one transfer, answers and counts
+                idx, vals, levels, lanes = jax.device_get(
+                    (idx, vals, info["levels"], info["lanes"])
+                )
+            self.push_path, self.lanes = info["push_path"], lanes.tolist()
             return None, idx, vals, int(levels)
         est = multi_source(key, g, eg, us, self.params, **common)
-        self.push_path = info["push_path"]
         with span(DISPATCH_FETCH):
-            est, levels = jax.device_get((est, info["levels"]))
+            est, levels, lanes = jax.device_get(
+                (est, info["levels"], info["lanes"])
+            )
+        self.push_path, self.lanes = info["push_path"], lanes.tolist()
         return est, None, None, int(levels)
 
     # -- updates -------------------------------------------------------------
@@ -722,7 +736,9 @@ class ShardedBackend:
     double-buffered ring exchange (``probe='ring'``) — and reduces
     per-query counts + top-k in the same program.  Zero host transfers
     mid-query; each query owns ``walk_chunk // Q`` lane columns (the
-    local fused path's schedule, shared via ``core.multisource``).
+    local fused path's full-batch schedule, shared via
+    ``core.multisource``; repeat-padding slots own theirs too, where the
+    local path deals every column to the live queries).
     The epilogue (1/n_r, truncation shift, diagonal fix, top-k) matches
     the local path's conventions so results are tolerance-comparable.
 
@@ -742,6 +758,7 @@ class ShardedBackend:
 
     name = "sharded"
     push_path = None  # the mesh probes keep their own pushes
+    lanes = None  # and their own lane schedule
     supports_epoch = True
     variants = ("auto", "telescoped")
 
@@ -1002,7 +1019,8 @@ class ShardedBackend:
         return dict(topk_nodes=idx[0], topk_scores=vals[0])
 
     def serve_batch(
-        self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int
+        self, kind: str, us, keys, *, key=None, k: int = 0, n_r: int,
+        live: int | None = None,
     ) -> tuple:
         """ONE lane-batched mesh dispatch per query batch.
 
@@ -1015,7 +1033,8 @@ class ShardedBackend:
         rebuilding from host buffers).  Compiled once per
         (Q, k, n_r, probe, capacity band); zero host transfers mid-query.
         Returns ``(est, idx, vals, None)``: the mesh step does not count
-        its probe levels.
+        its probe levels.  ``live`` is ignored: every slot owns
+        ``walk_chunk // Q`` lane columns, padding included.
         """
         us = np.asarray(us, np.int32).reshape(-1)
         q = us.shape[0]

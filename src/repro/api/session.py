@@ -100,7 +100,8 @@ class EngineStats:
     already in the hub probe cache.  ``probe_levels`` totals the probe
     levels of the fused serve dispatches whose backend counts them;
     ``push_path`` names the push the latest of them ran
-    (``core.multisource.push_path``).
+    (``core.multisource.push_path``) and ``lanes`` the fewest lane columns
+    a live query of it owned (the query that sets its length).
     """
 
     queries: int = 0
@@ -113,6 +114,7 @@ class EngineStats:
     hub_hits: int = 0
     probe_levels: int = 0
     push_path: str | None = None
+    lanes: int | None = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -551,7 +553,7 @@ class SimRankSession:
         self.stats.steps += 1
         self.stats.queries += spec.q
         self.stats.probe_levels += levels or 0
-        path = self._note_push_path(levels)
+        path, lanes = self._note_dispatch(levels, spec.q)
         return ResultEnvelope(
             kind=spec.kind,
             node=spec.node,
@@ -563,18 +565,24 @@ class SimRankSession:
             variant=self.backend.dispatch_label(variant),
             probe_levels=levels,
             push_path=path,
+            lanes=None if lanes is None else min(lanes),
             **out,
         )
 
-    def _note_push_path(self, levels) -> str | None:
-        """The push of the fused dispatch that just ran, kept in
-        ``stats.push_path``; None where the dispatch counted no levels
-        (one-shot variants, the mesh backend)."""
+    def _note_dispatch(self, levels, live: int):
+        """``(push_path, lanes)`` of the fused dispatch that just ran: its
+        push and the lane columns each slot owned, kept in
+        ``stats.push_path`` and, as the fewest over the ``live`` leading
+        slots, ``stats.lanes``; ``(None, None)`` where the dispatch
+        counted no levels (one-shot variants, the mesh backend)."""
         if levels is None:
-            return None
+            return None, None
         path = getattr(self.backend, "push_path", None)
+        lanes = getattr(self.backend, "lanes", None)
         self.stats.push_path = path
-        return path
+        if lanes is not None:
+            self.stats.lanes = min(lanes[:live])
+        return path, lanes
 
     def _multi_keys(self, spec: QuerySpec):
         """(key, keys) for a batched spec — exactly one of the two is set."""
@@ -645,6 +653,7 @@ class SimRankSession:
             rounds=max(e.rounds for e in envs),
             probe_levels=envs[0].probe_levels,
             push_path=envs[0].push_path,
+            lanes=envs[0].lanes,
         )
 
     def _serve_adaptive(
@@ -705,7 +714,7 @@ class SimRankSession:
                 cacheable.append(False)
         ver = self.version
         levels = None  # summed over the rounds that dispatched
-        path = None
+        path = lanes = None
         t0 = time.perf_counter()
         while True:
             n_round = ctrl.next_round()
@@ -747,7 +756,7 @@ class SimRankSession:
                 if lv is not None:
                     levels = (levels or 0) + lv
                     self.stats.probe_levels += lv
-                path = self._note_push_path(lv) or path
+                    path, lanes = self._note_dispatch(lv, q)
                 self.stats.steps += 1
                 if r > 0:
                     self.stats.escalations += 1
@@ -777,6 +786,7 @@ class SimRankSession:
                 rounds=cert.rounds,
                 probe_levels=levels,
                 push_path=path,
+                lanes=None if lanes is None else lanes[i],
             )
             if sp.kind == "single_source":
                 env.scores = scores
@@ -833,7 +843,15 @@ class SimRankSession:
         )
 
     def _pop_query_batch(self) -> tuple[list[tuple[QuerySpec, Array]], int]:
-        """Pop up to ``batch_q`` group-compatible specs; repeat-pad the rest."""
+        """Pop up to ``batch_q`` group-compatible specs; repeat-pad the rest.
+
+        Returns ``(batch, live)``: the batch keeps the static ``batch_q``
+        shape, so one compiled step serves it, and ``live`` counts its
+        popped specs.  A flat dispatch deals every lane column to those
+        ``live`` queries and none to the padding slots (``core.multisource
+        .lane_owner``), so a short batch runs in the levels its own walks
+        need; epochs and adaptive rounds still run every slot.
+        """
         gid = self._batch_group(self.query_queue[0][0])
         batch: list[tuple[QuerySpec, Array]] = []
         while (
@@ -851,14 +869,18 @@ class SimRankSession:
         self,
         batch: list[tuple],
         budget_walks: int | None,
+        live: int | None = None,
     ) -> list[ResultEnvelope]:
         """One fused dispatch for a (possibly repeat-padded) query batch.
 
         Items are ``(spec, key)`` or ``(spec, key, ticket)`` tuples; the
-        returned envelope list is positional (tickets — when present —
+        first ``live`` (None: all) are real and own every lane column, the
+        rest repeat-pad the batch and get envelopes without an estimate.
+        The returned envelope list is positional (tickets — when present —
         are filled by the caller for the live slice only, so repeat
         padding never double-assigns).  Adaptive groups (``epsilon`` set)
-        route to the escalation loop instead of one flat dispatch.
+        route to the escalation loop instead of one flat dispatch, with
+        every slot live.
         """
         spec0 = batch[0][0]
         if spec0.epsilon is not None:
@@ -868,11 +890,13 @@ class SimRankSession:
         keys = jnp.stack([item[1] for item in batch])
         with span(DISPATCH) as sp:
             est, idx, vals, levels = self.backend.serve_batch(
-                spec0.kind, us, keys, k=spec0.k or 0, n_r=n_r
+                spec0.kind, us, keys, k=spec0.k or 0, n_r=n_r, live=live
             )
         self.stats.steps += 1
         self.stats.probe_levels += levels or 0
-        path = self._note_push_path(levels)
+        path, lanes = self._note_dispatch(
+            levels, len(batch) if live is None else live
+        )
         ver = self.version
         bound = self.error_bound(n_r)
         return [
@@ -889,6 +913,7 @@ class SimRankSession:
                 variant=self.backend.dispatch_label("telescoped"),
                 probe_levels=levels,
                 push_path=path,
+                lanes=None if lanes is None else lanes[i],
             )
             for i, item in enumerate(batch)
         ]
@@ -906,7 +931,7 @@ class SimRankSession:
             if not self.query_queue:
                 return []
             batch, live = self._pop_query_batch()
-            served = self._serve_fused(batch, budget_walks)[:live]
+            served = self._serve_fused(batch, budget_walks, live)[:live]
             for item, env in zip(batch[:live], served):
                 if len(item) > 2 and item[2] is not None:
                     item[2].envelope = env
@@ -918,8 +943,9 @@ class SimRankSession:
 
         Consecutive group-compatible specs (same kind/k/budget) share a
         dispatch; short or cut batches are padded by repeating the last
-        entry (padded slots recompute an already-served query and are
-        discarded).  ``budget_walks`` caps specs that don't pin their own.
+        entry to keep one compiled shape (padded slots own no lane column
+        and their rows are discarded).  ``budget_walks`` caps specs that
+        don't pin their own.
         Tickets already forced via ``result()`` have left the queue — the
         returned list covers what was still queued, in order.
         """

@@ -125,7 +125,10 @@ class ResultEnvelope:
     path does not count them (one-shot legacy variants, fused epochs,
     the sharded backend).  ``push_path`` names the push those levels ran
     (``core.multisource.push_path``: ``csr_kernel``, ``coo_xla``, ...),
-    None where ``probe_levels`` is.
+    and ``lanes`` the lane columns this query owned in the dispatch (the
+    live queries of a short batch share all of them; for a batched spec,
+    the fewest any of its queries owned); both None where
+    ``probe_levels`` is.
 
     Field-superset of the legacy ``QueryResult`` — engine shims return
     envelopes directly.
@@ -148,3 +151,4 @@ class ResultEnvelope:
     rounds: int = 1
     probe_levels: int | None = None
     push_path: str | None = None
+    lanes: int | None = None

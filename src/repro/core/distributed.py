@@ -282,7 +282,7 @@ def lane_probe_block(
     )
 
     w = q * wq
-    _, qid = lane_columns(q, wq)
+    _, qid = lane_columns(q, wq, q)
     max_steps = lane_max_steps(n_r, max_len)
 
     def cond(state):
@@ -292,7 +292,7 @@ def lane_probe_block(
     def body(state):
         step, pos, widx, next_q, scores, total = state
         fin, pos, widx, next_q = lane_refill(
-            pos, widx, next_q, pool_len, qid, q=q, wq=wq, n_r=n_r
+            pos, widx, next_q, pool_len, qid, n_r=n_r
         )
         active, u_p, u_prev = lane_frontier(pool, widx, pos, sentinel)
         thr = lane_thresholds(pos, sqrt_c=sqrt_c, eps_p=eps_p)

@@ -142,7 +142,7 @@ def epoch_step(
         g2, eg2 = state
         keys_b, us_b, acc_b = qb
         return fused_serve_impl(
-            keys_b, g2, eg2, us_b, acc_b,
+            keys_b, g2, eg2, us_b, acc_b, jnp.int32(us_b.shape[0]),
             n_r=n_r,
             lanes_q=lanes_q,
             max_len=max_len,
@@ -158,7 +158,7 @@ def epoch_step(
         lambda state, b: _pair_apply(state, b), probe
     )
     (g2, eg2), applied, out = run((g, eg), batch, (keys, us, acc))
-    acc, est, idx, vals, _ = out  # the level count is not reported here
+    acc, est, idx, vals, _, _ = out  # levels and lanes are not reported here
     return g2, eg2, applied, est, idx, vals
 
 

@@ -8,8 +8,9 @@ only ~1/(1 - sqrt(c)) nodes long.  ``multi_source`` replaces all of that with
 ONE compiled step per query batch:
 
 * **query batching across the lane dimension** — Q queries share a single
-  [n + 1, W] score buffer; each query owns a contiguous block of W/Q lane
-  columns, so every push level is one SpMM dispatch for the whole batch;
+  [n + 1, W] score buffer; each live query owns a contiguous block of about
+  W/live lane columns (W/Q in a full batch; padding slots own none), so
+  every push level is one SpMM dispatch for the whole batch;
 * **pooled walk sampling** — the entire walk pool (Q x n_r walks) is drawn
   by one vmapped sampler call inside the same jit.  Per-chunk sampling pays
   a large fixed dispatch cost (the ELL-table walk); pooling amortizes it;
@@ -25,7 +26,7 @@ ONE compiled step per query batch:
   [n + 1, W] (row n = dump row), so sentinel scatter/gather indices need no
   clipping and the SpMM kernel path never re-pads ``scores``
   (``push_level_padded`` / ``spmm_ell_padded``);
-* **fused epilogue** — per-query segment reduction (lane-block sum), the
+* **fused epilogue** — per-query segment reduction (owned-column sum), the
   1/n_r normalization, the diagonal fix-up and ``lax.top_k`` all run inside
   the same compiled step, with the [Q, n] accumulator donated by the caller.
 
@@ -48,6 +49,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.params import ProbeSimParams
 from repro.core.probe import push_level_padded
@@ -71,10 +73,38 @@ Array = jax.Array
 # float summation order alone.
 
 
-def lane_columns(q: int, wq: int) -> tuple[Array, Array]:
-    """Column ids [W] and the owning query of each lane column [W]."""
-    cols = jnp.arange(q * wq)
-    return cols, cols // wq
+def lane_owner(cols, live, w: int):
+    """Owning query of each of the ``w`` lane columns ``cols``: the first
+    ``live`` queries deal the columns among themselves in contiguous
+    blocks, in query order, of ``w // live`` columns or one more; later
+    (padding) slots own none.  ``live`` may be a Python int or a traced
+    int32 scalar.  With every slot live (``live == q``, ``w == q * wq``)
+    it is ``cols // wq``."""
+    return cols * live // w
+
+
+def lane_columns(q: int, wq: int, live) -> tuple[Array, Array]:
+    """Column ids [W] (W = q * wq) and the owning query of each [W]
+    (:func:`lane_owner`) when the first ``live`` of the ``q`` slots are
+    live; with ``live == q`` query i owns the columns
+    ``[i * wq, (i + 1) * wq)``."""
+    w = q * wq
+    cols = jnp.arange(w)
+    return cols, lane_owner(cols, live, w)
+
+
+def _owner_sum(x, qid, q: int):
+    """Per-query sums [Q] of an int [W] vector over the columns each owns."""
+    own = qid[None, :] == jnp.arange(q)[:, None]
+    return jnp.where(own, x[None, :], 0).sum(axis=1)
+
+
+def _owner_rank(flag, qid, q: int):
+    """For each column, the flagged columns of its owner before it.
+    Owners hold contiguous blocks in query order, so this is the global
+    exclusive prefix count less the flagged columns of earlier owners."""
+    per_q = _owner_sum(flag, qid, q)
+    return jnp.cumsum(flag) - flag - (jnp.cumsum(per_q) - per_q)[qid]
 
 
 def lane_max_steps(n_r: int, max_len: int) -> int:
@@ -87,48 +117,33 @@ def lane_continue(step, pos, next_q, *, n_r: int, max_steps: int):
     return (step < max_steps) & (jnp.any(pos >= 1) | jnp.any(next_q < n_r))
 
 
-def lane_refill(pos, widx, next_q, pool_len, qid, *, q, wq, n_r):
+def lane_refill(pos, widx, next_q, pool_len, qid, *, n_r):
     """Column bookkeeping for one level: finished-column detection plus
     sticky per-query refill from the pool.
 
     Pure [W]-vector arithmetic — no score movement — so the fused Pallas
-    level kernel and the XLA level composition share it verbatim.  Returns
+    level kernels and the XLA level composition share it verbatim.
+    ``qid`` [W] is each column's owner (:func:`lane_columns`, a runtime
+    array when the live count is data); ``next_q`` [Q] the per-query pool
+    cursor, ``n_r`` for a slot that draws nothing.  Returns
     ``(fin, pos, widx, next_q)``; ``fin`` marks the columns whose walk just
-    finished (the caller deposits their scores into ``total``).  Refill
-    pulls walks from each query's pool partition in pool order — selection
-    is content-independent, so the estimator stays unbiased.
+    finished (the caller deposits their scores into ``total``).  An idle
+    column takes its owner's next walk by its rank among the owner's idle
+    columns: refill pulls walks from each query's pool partition in pool
+    order — selection is content-independent, so the estimator stays
+    unbiased.
     """
-    w = q * wq
+    q = next_q.shape[0]
     fin = pos == 1
     pos = jnp.where(fin, 0, pos)
-    idle = (pos == 0).astype(jnp.int32).reshape(q, wq)
-    rank = (jnp.cumsum(idle, axis=1) - idle).reshape(w)
+    idle = (pos == 0).astype(jnp.int32)
+    rank = _owner_rank(idle, qid, q)
     take = (pos == 0) & (rank < (n_r - next_q)[qid])
     new_widx = qid * n_r + jnp.minimum(next_q[qid] + rank, n_r - 1)
     widx = jnp.where(take, new_widx, widx)
     pos = jnp.where(take, pool_len[new_widx], pos)
-    next_q = next_q + take.astype(jnp.int32).reshape(q, wq).sum(axis=1)
+    next_q = next_q + _owner_sum(take.astype(jnp.int32), qid, q)
     return fin, pos, widx, next_q
-
-
-def lane_deposit_refill(
-    pos, widx, next_q, scores, total, pool_len, qid, *, q, wq, n_r
-):
-    """Deposit finished columns into ``total`` and refill idle columns.
-
-    ``scores``/``total`` are [rows, W] blocks (any row count — the helpers
-    only touch them columnwise); ``pos``/``widx`` are per-column int32 [W],
-    ``next_q`` the per-query pool cursor [Q].  Composition of
-    ``lane_refill`` with the columnwise score movement; kept for callers
-    that fuse the deposit into their own level (the Pallas kernel path
-    calls ``lane_refill`` directly and deposits on-chip).
-    """
-    fin, pos, widx, next_q = lane_refill(
-        pos, widx, next_q, pool_len, qid, q=q, wq=wq, n_r=n_r
-    )
-    total = total + jnp.where(fin[None, :], scores, 0.0)
-    scores = jnp.where(fin[None, :], 0.0, scores)
-    return pos, widx, next_q, scores, total
 
 
 def lane_frontier(pool, widx, pos, sentinel: int):
@@ -188,6 +203,7 @@ def fused_serve_impl(
     eg: EllGraph,
     us: Array,  # int32 [Q]
     acc: Array,  # f32 [Q, n] donated accumulator (usually zeros)
+    live: Array,  # int32 scalar: the leading slots that are real queries
     *,
     n_r: int,
     lanes_q: int,
@@ -202,6 +218,15 @@ def fused_serve_impl(
 ):
     """One fused serve step: sample pool -> compacted probe -> estimates.
 
+    ``live`` (a traced int32 scalar, so one executable serves every count)
+    marks the first ``live`` of the Q slots as real queries: they deal all
+    W = Q * ``lanes_q`` lane columns among themselves (:func:`lane_owner`);
+    the padding slots behind them own no column and push none of their
+    walks (the pool still samples them), so a step's levels follow the
+    live queries' walks, not Q's.  Padding rows of ``est`` carry no
+    estimate.  ``live == Q`` is the full schedule: query i owns the
+    columns ``[i * lanes_q, (i + 1) * lanes_q)``.
+
     ``use_kernel=True`` runs each probe level through the fused Pallas
     lane-probe kernel (``kernels/lane_probe``) against the ELL push table;
     ``kernel_dtype="bfloat16"`` additionally stores the score/accumulator
@@ -210,35 +235,36 @@ def fused_serve_impl(
     the fused CSR kernel over a dst-sorted view of the COO graph, built
     once here (sums in CSR order: 1e-5 of the XLA level); else the XLA
     level.  Returns
-    ``(acc, est, topk_idx, topk_vals, levels)``; the top-k outputs are
-    None when ``top_k == 0``.  ``levels`` (int32 scalar) counts the probe
-    levels the step ran: the peeled first level plus the loop's trips.
+    ``(acc, est, topk_idx, topk_vals, levels, lanes)``; the top-k outputs
+    are None when ``top_k == 0``.  ``levels`` (int32 scalar) counts the
+    probe levels the step ran, ``lanes`` (int32 [Q]) the lane columns
+    each slot owned.
     """
     n = eg.n
     q = us.shape[0]
     wq = lanes_q
     w = q * wq
-    cols, qid = lane_columns(q, wq)
+    cols, qid = lane_columns(q, wq, live)
+    lanes = _owner_sum(jnp.ones(w, jnp.int32), qid, q)
+    # per-query pool cursors; a padding slot starts drained
+    next0 = jnp.where(jnp.arange(q) < live, 0, n_r).astype(jnp.int32)
     dtype = (
         jnp.bfloat16
         if (use_kernel and kernel_dtype == "bfloat16")
         else jnp.float32
     )
 
-    # --- walk pool, pipelined against the first push level ----------------
-    # All per-(walk, step) uniforms are drawn up front (bit-identical to a
-    # single pooled sample_walks_batch call); only the first wq walks per
-    # query — the ones the first refill can possibly claim — are
-    # materialized before level 1.  The remaining (n_r - wq) walks'
-    # ELL-table scans carry no data dependency on the level loop, so they
-    # overlap the first push level instead of serializing ahead of it
-    # (~20% of the step, ROADMAP).
-    h = min(wq, n_r)
+    # --- walk pool ----------------------------------------------------------
+    # All Q x n_r walks, from per-(walk, step) uniforms drawn up front
+    # (bit-identical to a pooled sample_walks_batch call).  Padding slots
+    # draw theirs too, and no column ever takes them.
     cont, pick = jax.vmap(
         lambda k: walk_uniforms(k, n_r=n_r, max_len=max_len, sqrt_c=sqrt_c)
     )(keys)
-    walks_of = jax.vmap(lambda u1, c, p: walks_from_uniforms(eg, u1, c, p))
-    head = walks_of(us, cont[:, :h], pick[:, :h])  # [Q, h, max_len]
+    pool = jax.vmap(lambda u1, c, p: walks_from_uniforms(eg, u1, c, p))(
+        us, cont, pick
+    ).reshape(q * n_r, max_len)
+    pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
 
     # --- one probe level: deposit + inject + prune + push + exclude -------
     path = push_path(g, w, use_kernel=use_kernel)
@@ -283,17 +309,17 @@ def fused_serve_impl(
     # Per-column state: pos (current walk position; 1/0 = finished/idle),
     # widx (walk id in the flattened pool), next_q (per-query pool cursor).
     # `total` accumulates finished columns; per-query reduction happens once
-    # at the end (columns are query-sticky, so lane-block sums separate).
+    # at the end (columns are query-sticky, so per-owner sums separate).
     max_steps = lane_max_steps(n_r, max_len)
 
     def cond(state):
         step, pos, widx, next_q, scores, total = state
         return lane_continue(step, pos, next_q, n_r=n_r, max_steps=max_steps)
 
-    def body(state, pool, pool_len):
+    def body(state):
         step, pos, widx, next_q, scores, total = state
         fin, pos, widx, next_q = lane_refill(
-            pos, widx, next_q, pool_len, qid, q=q, wq=wq, n_r=n_r
+            pos, widx, next_q, pool_len, qid, n_r=n_r
         )
         # one telescoped level per active column, at its own position
         active, u_p, u_prev = lane_frontier(pool, widx, pos, n)
@@ -306,36 +332,25 @@ def fused_serve_impl(
         jnp.int32(0),
         jnp.zeros(w, jnp.int32),  # pos: all idle -> first iteration refills
         jnp.zeros(w, jnp.int32),  # widx
-        jnp.zeros(q, jnp.int32),  # next_q
+        next0,  # next_q
         jnp.zeros((rows, w), dtype),  # scores (baked dump row)
         jnp.zeros((rows, w), dtype),  # total (baked dump row)
     )
-    # First level runs against the head-only pool (the first refill can only
-    # claim head walks, so this is bit-identical to the full-pool level);
-    # the tail walks materialize concurrently with it.
-    if h < n_r:
-        head_pool = jnp.concatenate(
-            [head, jnp.full((q, n_r - h, max_len), n, jnp.int32)], axis=1
-        ).reshape(q * n_r, max_len)
-        head_len = (head_pool < n).sum(axis=1).astype(jnp.int32)
-        state = body(state, head_pool, head_len)
-        tail = walks_of(us, cont[:, h:], pick[:, h:])
-        pool = jnp.concatenate([head, tail], axis=1).reshape(
-            q * n_r, max_len
-        )
-        pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
-    else:
-        pool = head.reshape(q * n_r, max_len)
-        pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
-        state = body(state, pool, pool_len)
-    levels, pos, _, _, scores, total = jax.lax.while_loop(
-        cond, lambda s: body(s, pool, pool_len), state
-    )
+    levels, pos, _, _, scores, total = jax.lax.while_loop(cond, body, state)
     # safety-net flush (no-op unless max_steps was hit)
     total = total + jnp.where((pos == 1)[None, :], scores, 0.0)
 
     # --- per-query segment reduction + epilogue ---------------------------
-    acc = acc + total[:n].astype(jnp.float32).reshape(n, q, wq).sum(axis=2).T
+    tot = total[:n].astype(jnp.float32)
+
+    def by_block():  # every slot live: query i owns lane block i
+        return tot.reshape(n, q, wq).sum(axis=2).T
+
+    def by_owner():  # an exact masked sum; no matmul, which rounds on a TPU
+        own = qid[None, :] == jnp.arange(q)[:, None]  # [Q, W]
+        return jnp.where(own[None], tot[:, None, :], 0.0).sum(axis=2).T
+
+    acc = acc + jax.lax.cond(live == q, by_block, by_owner)
     est = acc / n_r
     if truncation_shift:
         est = jnp.where(est > 0, est + eps_t / 2, est)
@@ -343,8 +358,8 @@ def fused_serve_impl(
     if top_k > 0:
         masked = est.at[jnp.arange(q), us].set(-jnp.inf)
         vals, idx = jax.lax.top_k(masked, top_k)
-        return acc, est, idx, vals, levels
-    return acc, est, None, None, levels
+        return acc, est, idx, vals, levels, lanes
+    return acc, est, None, None, levels, lanes
 
 
 # The standalone jitted entry point.  ``fused_serve_impl`` stays un-jitted so
@@ -380,19 +395,22 @@ def _query_keys(key: Array | None, keys: Array | None, q: int) -> Array:
 
 def _serve(
     key, g, eg, us, params, *, k, lanes, use_kernel, kernel_dtype, n_r,
-    keys, info,
+    keys, info, live,
 ):
     """One jitted fused step for :func:`multi_source` (``k == 0``) and
     :func:`multi_source_topk`; returns ``(est, idx, vals)`` on the device
-    and puts the step's probe-level count (int32 scalar) in
-    ``info["levels"]`` and its :func:`push_path` in ``info["push_path"]``
-    when ``info`` is a dict."""
+    and, when ``info`` is a dict, puts the step's probe-level count (int32
+    scalar) in ``info["levels"]``, its :func:`push_path` in
+    ``info["push_path"]`` and the lane columns each slot owned (int32
+    [Q], from the step) in ``info["lanes"]``.  ``live`` None serves every
+    slot."""
     us = jnp.asarray(us, jnp.int32)
     q = int(us.shape[0])
     lanes_q = max(1, lanes // q)
     acc = jnp.zeros((q, g.n), jnp.float32)
-    _, est, idx, vals, levels = _fused_serve(
+    _, est, idx, vals, levels, owned = _fused_serve(
         _query_keys(key, keys, q), g, eg, us, acc,
+        np.int32(q if live is None else live),
         n_r=int(n_r or params.n_r),
         lanes_q=lanes_q,
         max_len=params.max_len,
@@ -407,6 +425,7 @@ def _serve(
     if info is not None:
         info["levels"] = levels
         info["push_path"] = push_path(g, q * lanes_q, use_kernel=use_kernel)
+        info["lanes"] = owned
     return est, idx, vals
 
 
@@ -423,12 +442,14 @@ def multi_source(
     n_r: int | None = None,
     keys: Array | None = None,
     info: dict | None = None,
+    live: int | None = None,
 ) -> Array:
     """Fused multi-query single-source SimRank: estimates [Q, n].
 
     ``us`` is int32 [Q]; ``g`` is the push representation (COO or ELL), ``eg``
     the ELL table used for walk sampling.  ``lanes`` is the total lane-column
-    width shared by the batch (each query owns ``lanes // Q`` columns).
+    width shared by the batch (each query owns ``lanes // Q`` columns, or
+    about ``lanes / live`` under ``live``).
     ``use_kernel=True`` serves every probe level through the fused Pallas
     lane-probe kernel (bitwise-equal to the XLA ELL path in fp32);
     ``kernel_dtype="bfloat16"`` stores the lane buffers bf16 with fp32
@@ -436,12 +457,18 @@ def multi_source(
     serving).  Pass per-query ``keys`` ([Q] typed key array) for
     batch-vs-serial determinism; otherwise ``key`` is split into Q streams.
     A dict passed as ``info`` receives ``"levels"``: the number of probe
-    levels the step ran, as an int32 device scalar, and ``"push_path"``:
-    the push its levels ran (:func:`push_path`).
+    levels the step ran, as an int32 device scalar, ``"push_path"``: the
+    push its levels ran (:func:`push_path`), and ``"lanes"``: the lane
+    columns each query owned, as an int32 [Q] device array.  ``live``
+    serves only the first ``live`` queries of ``us``: they share every
+    lane column and the rest are padding with no estimate
+    (``fused_serve_impl``); the count is data, so one compiled step
+    serves every ``live`` for a batch shape.
     """
     est, _, _ = _serve(
         key, g, eg, us, params, k=0, lanes=lanes, use_kernel=use_kernel,
         kernel_dtype=kernel_dtype, n_r=n_r, keys=keys, info=info,
+        live=live,
     )
     return est
 
@@ -460,15 +487,17 @@ def multi_source_topk(
     n_r: int | None = None,
     keys: Array | None = None,
     info: dict | None = None,
+    live: int | None = None,
 ) -> tuple[Array, Array]:
     """Fused batched top-k (paper Def. 2): (nodes [Q, k], estimates [Q, k]).
 
     The query node itself is excluded; ``top_k`` runs inside the same
-    compiled step as sampling and the probe.  ``info`` as for
-    :func:`multi_source`.
+    compiled step as sampling and the probe.  ``info`` and ``live`` as
+    for :func:`multi_source`.
     """
     _, idx, vals = _serve(
         key, g, eg, us, params, k=k, lanes=lanes, use_kernel=use_kernel,
         kernel_dtype=kernel_dtype, n_r=n_r, keys=keys, info=info,
+        live=live,
     )
     return idx, vals

@@ -42,9 +42,7 @@ def walk_uniforms(
     coins (bool, continue w.p. sqrt(c)) and the neighbor-pick uniforms.
     Walks are row-independent given these draws, so any row subset can be
     materialized separately (``walks_from_uniforms``) and still be
-    bit-identical to a full-pool ``sample_walks`` call — the property the
-    pipelined serve path (DESIGN.md §3) relies on to overlap tail-walk
-    sampling with the first push level.
+    bit-identical to a full-pool ``sample_walks`` call.
     """
     k_cont, k_step = jax.random.split(key)
     cont = jax.random.uniform(k_cont, (n_r, max_len - 1)) < sqrt_c

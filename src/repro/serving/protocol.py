@@ -239,6 +239,8 @@ def envelope_to_wire(env, **extra) -> dict:
         out["probe_levels"] = int(env.probe_levels)
     if env.push_path is not None:
         out["push_path"] = env.push_path
+    if env.lanes is not None:
+        out["lanes"] = int(env.lanes)
     out.update({k: _jsonable(v) for k, v in extra.items()})
     return out
 

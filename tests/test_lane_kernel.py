@@ -1,5 +1,5 @@
 """Fused lane-probe level kernel (PR 10): op vs jnp oracle bitwise in fp32
-(interpret mode), bf16 storage parity, edge-case shapes, the pipelined
+(interpret mode), bf16 storage parity, edge-case shapes, the
 walk-sampling split, end-to-end local serve parity, and the sharded
 use_kernel=True mesh paths (subprocess: XLA_FLAGS must precede jax init)."""
 import os
@@ -157,8 +157,8 @@ def test_bf16_storage_fp32_accumulate(rng):
 
 
 # ---------------------------------------------------------------------------
-# Pipelined walk sampling: row subsets of one uniform draw are bitwise
-# identical to the full-pool walk (what lets tail sampling overlap level 1)
+# Walks from pre-drawn uniforms: row subsets of one uniform draw are
+# bitwise identical to the full-pool walk
 # ---------------------------------------------------------------------------
 
 

@@ -6,6 +6,8 @@ Covers the tentpole contracts:
 * ``multi_source(us=[u])`` IS ``single_source(u, variant='telescoped')``;
 * batched results are identical to per-query results given per-query keys
   (the engine's batched ``drain()`` == serial serving property);
+* the live queries of a short batch share every lane column (padding slots
+  own none), and a full batch keeps the lane-block schedule bit for bit;
 * COO push, ELL push and the Pallas kernel path agree.
 """
 import numpy as np
@@ -21,6 +23,7 @@ from repro.core import (
     single_source,
     simrank_power,
 )
+from repro.core.multisource import lane_columns, lane_refill
 from repro.core.probe import probe_walks_telescoped
 from repro.core.walks import sample_walks_batch
 from repro.graph import ell_from_edges, graph_from_edges, powerlaw_graph
@@ -84,6 +87,128 @@ def test_batch_matches_per_query(small_powerlaw, key):
         np.testing.assert_allclose(
             np.asarray(batch[i]), np.asarray(solo[0]), atol=1e-5
         )
+
+
+def _padded(us, keys, live):
+    """A batch of len(us) slots: the first ``live`` queries, then repeats
+    of the last live one (the session's padding)."""
+    q = len(us)
+    idx = np.minimum(np.arange(q), live - 1)
+    return jnp.asarray(np.asarray(us)[idx], jnp.int32), keys[idx]
+
+
+@pytest.mark.parametrize("live", [1, 2, 3])
+def test_short_batch_matches_solo(small_powerlaw, key, live):
+    """Live queries of a short batch share every lane column; each one's
+    estimate equals that query served alone up to the order of f32 sums,
+    and the padding slots own no column."""
+    g, eg = small_powerlaw["g"], small_powerlaw["eg"]
+    params = make_params(small_powerlaw["n"], c=0.6, eps_a=0.2,
+                         n_r_override=150)
+    us = np.argsort(-np.asarray(g.in_deg))[:4].astype(np.int32)
+    keys = jax.random.split(key, 4)
+    pus, pkeys = _padded(us, keys, live)
+    info = {}
+    batch = multi_source(None, g, eg, pus, params, lanes=64, keys=pkeys,
+                         live=live, info=info)
+    lanes = np.asarray(info["lanes"]).tolist()
+    assert sum(lanes) == 64
+    assert lanes[live:] == [0] * (4 - live)
+    assert min(lanes[:live]) == 64 // live
+    for i in range(live):
+        solo = multi_source(
+            None, g, eg, us[i : i + 1], params, lanes=64, keys=keys[i : i + 1]
+        )
+        np.testing.assert_allclose(
+            np.asarray(batch[i]), np.asarray(solo[0]), atol=1e-5
+        )
+
+
+def _block_refill(pos, widx, next_q, pool_len, *, q, wq, n_r):
+    """The every-slot-live refill as lane blocks: query i owns the columns
+    [i * wq, (i + 1) * wq), ranks and takes counted per block."""
+    w = q * wq
+    qid = jnp.arange(w) // wq
+    fin = pos == 1
+    pos = jnp.where(fin, 0, pos)
+    idle = (pos == 0).astype(jnp.int32).reshape(q, wq)
+    rank = (jnp.cumsum(idle, axis=1) - idle).reshape(w)
+    take = (pos == 0) & (rank < (n_r - next_q)[qid])
+    new_widx = qid * n_r + jnp.minimum(next_q[qid] + rank, n_r - 1)
+    widx = jnp.where(take, new_widx, widx)
+    pos = jnp.where(take, pool_len[new_widx], pos)
+    next_q = next_q + take.astype(jnp.int32).reshape(q, wq).sum(axis=1)
+    return fin, pos, widx, next_q
+
+
+def test_full_batch_keeps_the_block_schedule(small_powerlaw, key):
+    """live == Q is the lane-block schedule bit for bit: its owners are
+    ``cols // wq``, the refill takes the same walks into the same columns
+    level after level, and the step reports W / Q columns a query."""
+    q, wq, n_r = 4, 16, 40
+    cols, qid = lane_columns(q, wq, jnp.int32(q))
+    assert np.array_equal(np.asarray(qid), np.asarray(cols) // wq)
+    rng = np.random.default_rng(0)
+    pool_len = jnp.asarray(rng.integers(1, 6, q * n_r), jnp.int32)
+    pos = jnp.asarray(rng.integers(0, 4, q * wq), jnp.int32)
+    widx = jnp.asarray(rng.integers(0, q * n_r, q * wq), jnp.int32)
+    next_q = jnp.asarray(rng.integers(0, n_r, q), jnp.int32)
+    a = b = (pos, widx, next_q)
+    for _ in range(60):  # drains every pool: the tail levels too
+        fa, *a = lane_refill(*a, pool_len, qid, n_r=n_r)
+        fb, *b = _block_refill(*b, pool_len, q=q, wq=wq, n_r=n_r)
+        for x, y in zip([fa, *a], [fb, *b]):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+        a[0] = jnp.where(a[0] >= 1, a[0] - 1, a[0])
+        b[0] = jnp.where(b[0] >= 1, b[0] - 1, b[0])
+    assert np.asarray(a[2]).tolist() == [n_r] * q
+
+    g, eg = small_powerlaw["g"], small_powerlaw["eg"]
+    params = make_params(small_powerlaw["n"], c=0.6, eps_a=0.2,
+                         n_r_override=150)
+    us = jnp.asarray(np.argsort(-np.asarray(g.in_deg))[:4], jnp.int32)
+    info = {}
+    multi_source_topk(None, g, eg, us, 5, params, lanes=64,
+                      keys=jax.random.split(key, 4), live=4, info=info)
+    assert np.asarray(info["lanes"]).tolist() == [16] * 4
+
+
+def test_live_one_step_runs_its_own_levels(small_powerlaw, key):
+    """A lone live query runs at most ceil(column-levels / W) + max_len
+    levels, W = every lane column; the padded schedule ran W / Q."""
+    g, eg, n = small_powerlaw["g"], small_powerlaw["eg"], small_powerlaw["n"]
+    params = make_params(n, c=0.6, eps_a=0.2, n_r_override=600)
+    u = int(np.argmax(np.asarray(g.in_deg)))
+    keys = jnp.stack([key] * 4)
+    us = jnp.full((4,), u, jnp.int32)
+    lone, padded = {}, {}
+    multi_source(None, g, eg, us, params, lanes=64, keys=keys, live=1,
+                 info=lone)
+    multi_source(None, g, eg, us, params, lanes=64, keys=keys, info=padded)
+    pool = sample_walks_batch(keys[:1], eg, us[:1], n_r=600,
+                              max_len=params.max_len, sqrt_c=params.sqrt_c)
+    # a walk of l nodes holds its column for max(l - 1, 1) levels
+    held = int(np.maximum((np.asarray(pool) < n).sum(-1) - 1, 1).sum())
+    assert int(lone["levels"]) <= -(-held // 64) + params.max_len
+    assert int(lone["levels"]) < int(padded["levels"])
+
+
+def test_padding_slots_claim_no_column():
+    """The refill never hands a padding slot's walk to a column, and a
+    padding slot's pool cursor stays drained."""
+    q, wq, n_r, live = 4, 8, 5, 3
+    _, qid = lane_columns(q, wq, jnp.int32(live))
+    assert np.bincount(np.asarray(qid), minlength=q).tolist() == [11, 11, 10, 0]
+    assert set(np.asarray(qid).tolist()) == {0, 1, 2}
+    pos = widx = jnp.zeros(q * wq, jnp.int32)
+    next_q = jnp.where(jnp.arange(q) < live, 0, n_r).astype(jnp.int32)
+    pool_len = jnp.full(q * n_r, 3, jnp.int32)
+    _, pos, widx, next_q = lane_refill(pos, widx, next_q, pool_len, qid,
+                                       n_r=n_r)
+    taken = np.asarray(widx)[np.asarray(pos) > 0]
+    assert (taken // n_r < live).all()
+    assert np.asarray(next_q).tolist() == [n_r] * q  # 11, 11, 10 >= 5
+    assert len(taken) == live * n_r and len(set(taken.tolist())) == len(taken)
 
 
 def test_fused_error_bound_toy(toy, key):

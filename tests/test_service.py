@@ -4,11 +4,12 @@ micro-batching parity, admission control, deadlines, tenancy, updates.
 The load-bearing test is ``test_microbatch_parity_and_fusion``: N client
 threads x 1 query each through the live HTTP server must be bitwise-equal
 to a direct session under matched streams (wire ``seed`` pins the lane
-PRNG stream; the reference replays each (node, key) through ``submit()``/
-``drain()`` at the same ``batch_q``, which PR 3's lane-composition
-invariance makes independent of how the collector actually grouped them)
-— AND the tenant session must report ``steps < queries`` (the window
-really fused cross-connection traffic into lane-batched dispatches).
+PRNG stream; the reference replays each dispatch the collector cut, and
+each (node, key) alone through ``submit()``/``drain()`` to f32 summation
+order: how many lane columns a query owns depends on how many queries
+its batch held) — AND the tenant session must report ``steps <
+queries`` (the window really fused cross-connection traffic into
+lane-batched dispatches).
 """
 import threading
 import time
@@ -106,6 +107,15 @@ def test_microbatch_parity_and_fusion(service_graph):
     handle, n = service_graph
     svc, server, thread = _live_server(handle, batch_window_ms=60.0)
     host, port = server.server_address
+    dispatches = []  # (us, keys, kwargs) of every fused dispatch
+    backend = svc.session().backend
+    serve = backend.serve_batch
+
+    def recorded(kind, us, keys, **kw):
+        dispatches.append((list(us), keys, kw))
+        return serve(kind, us, keys, **kw)
+
+    backend.serve_batch = recorded
     try:
         Q = 16
         results: list[dict | None] = [None] * Q
@@ -128,23 +138,49 @@ def test_microbatch_parity_and_fusion(service_graph):
         assert all(r is not None for r in results)
 
         # direct-session reference: same (node, key) streams, same
-        # batch_q geometry; lane-composition invariance means each solo
-        # replay is bitwise what the query's lane computed in whatever
-        # batch the collector cut
+        # batch_q geometry; replaying the dispatch the collector cut is
+        # bitwise what the query's lanes computed, a solo replay is that
+        # to f32 summation order (the live queries share all lanes)
         ref = SimRankSession(handle, batch_q=svc.config.max_batch_q)
+        slot_of, replays = {}, {}
+        key_id = lambda data: tuple(np.asarray(data).tolist())  # noqa: E731
+        for d, (_, keys, kw) in enumerate(dispatches):
+            data = jax.random.key_data(keys)
+            for j in range(kw["live"]):
+                slot_of[key_id(data[j])] = (d, j)
         for i, r in enumerate(results):
-            tk = ref.submit(QuerySpec(
-                kind="topk", node=i, k=5, budget_walks=64,
-                key=jax.random.key(500 + i),
-            ))
-            ref.drain()
-            env = tk.envelope
-            assert r["topk_nodes"] == np.asarray(env.topk_nodes).tolist()
+            key = jax.random.key(500 + i)
+            d, j = slot_of[key_id(jax.random.key_data(key))]
+            if d not in replays:
+                us, keys, kw = dispatches[d]
+                out = ref.backend.serve_batch("topk", us, keys, **kw)
+                replays[d] = (out, ref.backend.lanes)
+            (_, idx, vals, _), lanes = replays[d]
+            assert r["topk_nodes"] == np.asarray(idx[j]).tolist()
             # JSON carries exact float64 widenings of the float32 scores:
             # the cast back must be bit-identical
             assert np.array_equal(
+                np.asarray(r["topk_scores"], np.float32), vals[j]
+            )
+            assert r["lanes"] == lanes[j]
+            tk = ref.submit(QuerySpec(
+                kind="topk", node=i, k=5, budget_walks=64, key=key,
+            ))
+            ref.drain()
+            env = tk.envelope
+            np.testing.assert_allclose(
                 np.asarray(r["topk_scores"], np.float32),
-                np.asarray(env.topk_scores),
+                np.asarray(env.topk_scores), atol=1e-5,
+            )
+            # the nodes up to ties: the solo estimate at each returned
+            # node is the score returned for it
+            ss = ref.submit(QuerySpec(
+                kind="single_source", node=i, budget_walks=64, key=key,
+            ))
+            ref.drain()
+            np.testing.assert_allclose(
+                np.asarray(ss.envelope.scores)[r["topk_nodes"]],
+                np.asarray(r["topk_scores"], np.float32), atol=1e-5,
             )
             assert r["version"] == env.version
             assert r["walks_used"] == env.walks_used

@@ -148,7 +148,8 @@ def test_query_batched_parity(toy_session, toy, key):
 
 def test_drain_reproduces_fused_engine_dispatch(small_powerlaw):
     """submit/drain == the PR-1 engine formula: fold_in(seed, seq) streams,
-    repeat-padded fixed-size batches through multi_source_topk."""
+    fixed-size batches through multi_source_topk; the final short batch's
+    live query owns every lane column, so it equals that query alone."""
     g, eg, n = small_powerlaw["g"], small_powerlaw["eg"], small_powerlaw["n"]
     h = GraphHandle(g=g, eg=eg)
     qs = np.argsort(-np.asarray(g.in_deg))[:3].astype(int)  # 3 qs, batch_q=2
@@ -165,14 +166,37 @@ def test_drain_reproduces_fused_engine_dispatch(small_powerlaw):
         None, g, eg, jnp.asarray(qs[:2], jnp.int32), 5, params,
         lanes=64, n_r=96, keys=jnp.stack(streams[:2]),
     )
-    b1 = multi_source_topk(  # final short batch: repeat-padded
-        None, g, eg, jnp.asarray([qs[2], qs[2]], jnp.int32), 5, params,
-        lanes=64, n_r=96, keys=jnp.stack([streams[2], streams[2]]),
+    b1 = multi_source_topk(  # final short batch: its live query alone
+        None, g, eg, jnp.asarray([qs[2]], jnp.int32), 5, params,
+        lanes=64, n_r=96, keys=jnp.stack([streams[2]]),
     )
     assert np.array_equal(res[0].topk_scores, np.asarray(b0[1])[0])
     assert np.array_equal(res[1].topk_scores, np.asarray(b0[1])[1])
     assert np.array_equal(res[2].topk_scores, np.asarray(b1[1])[0])
     assert sess.stats.queries == 3 and sess.stats.steps == 2
+
+
+def test_short_batch_reports_its_lanes(small_powerlaw):
+    """A drained batch's answers report the lane columns their query
+    owned: the live queries of a short batch share all W of them, in the
+    envelope, on the wire and in ``stats.lanes``."""
+    from repro.serving.protocol import envelope_to_wire
+
+    h = GraphHandle(g=small_powerlaw["g"], eg=small_powerlaw["eg"])
+    sess = SimRankSession(h, c=0.6, eps_a=0.2, top_k=5, batch_q=4, seed=0,
+                          walk_chunk=64)
+    nodes = np.argsort(-np.asarray(h.g.in_deg))[:6].astype(int)
+    for u in nodes:
+        sess.submit(int(u))
+    res = sess.drain(budget_walks=64)
+    assert [r.lanes for r in res] == [16] * 4 + [32] * 2
+    assert sess.stats.lanes == 32
+    assert envelope_to_wire(res[5])["lanes"] == 32
+    assert res[4].probe_levels < res[0].probe_levels  # two walk sets, not 4
+    sess.submit(int(nodes[0]))
+    (lone,) = sess.drain(budget_walks=64)
+    assert lone.lanes == sess.stats.lanes == 64
+    assert lone.push_path == "coo_xla"
 
 
 def test_drain_cuts_batches_at_group_change(small_powerlaw):
@@ -534,7 +558,11 @@ def test_session_stats_threading(er_session):
 
 def test_concurrent_submit_drain_thread_safe():
     """Many threads submitting (+ some draining) concurrently: every
-    ticket gets exactly its own answer, bitwise-equal to a solo replay.
+    ticket gets exactly its own answer, bitwise-equal to a replay of its
+    slot in the dispatch that served it, and equal to a solo replay up to
+    the order of f32 sums, its nodes up to ties (a short batch deals its
+    lane columns to its live queries, so how many a query owns depends
+    on the batch).
 
     This is the contract the serving collector (serving/service.py)
     builds on: handler threads call ``submit()`` while the collector
@@ -547,6 +575,14 @@ def test_concurrent_submit_drain_thread_safe():
     h = GraphHandle.from_edges(src, dst, n)
     sess = SimRankSession(h, c=0.3, eps_a=0.3, top_k=3, batch_q=4, seed=0)
     sess.query(QuerySpec(kind="topk", node=0, budget_walks=16))  # warm jit
+    dispatches = []  # (us, keys, kwargs) of every fused dispatch
+    serve = sess.backend.serve_batch
+
+    def recorded(kind, us, keys, **kw):
+        dispatches.append((list(us), keys, kw))
+        return serve(kind, us, keys, **kw)
+
+    sess.backend.serve_batch = recorded
 
     T, PER = 8, 6
     tickets = [[None] * PER for _ in range(T)]
@@ -573,22 +609,48 @@ def test_concurrent_submit_drain_thread_safe():
     assert sess.stats.queries == T * PER + 1  # + the warm-jit query()
     assert not sess.query_queue
     ref = SimRankSession(h, c=0.3, eps_a=0.3, top_k=3, batch_q=4, seed=99)
+    # each ticket's (key, slot) in the dispatch that served it
+    slot_of = {}
+    key_id = lambda data: tuple(np.asarray(data).tolist())  # noqa: E731
+    for d, (_, keys, kw) in enumerate(dispatches):
+        data = jax.random.key_data(keys)
+        for i in range(kw["live"]):
+            slot_of[key_id(data[i])] = (d, i)
+    replays = {}
     for t in range(T):
         for j in range(PER):
             tk = tickets[t][j]
             assert tk is not None and tk.envelope is not None
             q = (t * PER + j) % n
             assert tk.envelope.node == q
-            rtk = ref.submit(QuerySpec(
-                kind="topk", node=q, k=3, budget_walks=16,
-                key=jax.random.key(10_000 + t * PER + j),
-            ))
-            ref.drain()
+            key = jax.random.key(10_000 + t * PER + j)
+            d, i = slot_of[key_id(jax.random.key_data(key))]
+            if d not in replays:
+                us, keys, kw = dispatches[d]
+                replays[d] = ref.backend.serve_batch("topk", us, keys, **kw)
+            _, idx, vals, _ = replays[d]
+            assert dispatches[d][0][i] == q
             np.testing.assert_array_equal(
-                np.asarray(tk.envelope.topk_nodes),
-                np.asarray(rtk.envelope.topk_nodes),
+                np.asarray(tk.envelope.topk_nodes), idx[i]
             )
             np.testing.assert_array_equal(
+                np.asarray(tk.envelope.topk_scores), vals[i]
+            )
+            rtk = ref.submit(QuerySpec(
+                kind="topk", node=q, k=3, budget_walks=16, key=key,
+            ))
+            ref.drain()
+            np.testing.assert_allclose(
                 np.asarray(tk.envelope.topk_scores),
-                np.asarray(rtk.envelope.topk_scores),
+                np.asarray(rtk.envelope.topk_scores), atol=1e-5,
+            )
+            # the nodes up to ties: the solo estimate at each returned
+            # node is the score returned for it
+            ss = ref.submit(QuerySpec(
+                kind="single_source", node=q, budget_walks=16, key=key,
+            ))
+            ref.drain()
+            np.testing.assert_allclose(
+                np.asarray(ss.envelope.scores)[tk.envelope.topk_nodes],
+                np.asarray(tk.envelope.topk_scores), atol=1e-5,
             )

@@ -173,9 +173,12 @@ def test_counter_leaves_answers_bitwise_unchanged(handle, top_k):
     def acc():
         return jnp.zeros((q, handle.n), jnp.float32)
 
-    _, est, idx, vals, levels = _fused_serve(keys, g, eg, us, acc(), **static)
+    live = jnp.int32(q)
+    _, est, idx, vals, levels, _ = _fused_serve(
+        keys, g, eg, us, acc(), live, **static
+    )
     without = jax.jit(lambda *a: fused_serve_impl(*a, **static)[:4])
-    _, est0, idx0, vals0 = without(keys, g, eg, us, acc())
+    _, est0, idx0, vals0 = without(keys, g, eg, us, acc(), live)
     if top_k:
         assert np.array_equal(np.asarray(idx), np.asarray(idx0))
         assert np.array_equal(np.asarray(vals), np.asarray(vals0))

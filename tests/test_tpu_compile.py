@@ -106,7 +106,7 @@ def test_fused_serve_fits_one_chip_at_hepph(one_chip):
     keys = s((q,), jax.random.key(0).dtype)
     p = make_params(n, c=0.6, eps_a=0.1, delta=0.01)
     compiled = _fused_serve.lower(
-        keys, g, eg, s((q,)), s((q, n), jnp.float32),
+        keys, g, eg, s((q,)), s((q, n), jnp.float32), s(()),
         n_r=p.n_r, lanes_q=16, max_len=p.max_len, sqrt_c=p.sqrt_c,
         eps_p=p.eps_p, eps_t=p.eps_t, truncation_shift=False,
         use_kernel=False, top_k=50,
