@@ -129,6 +129,8 @@ class Backend(Protocol):
     push_path: str | None
     # the lane columns each slot of that dispatch owned, or None
     lanes: list[int] | None
+    # the walks that dispatch's pool materialized, or None
+    walks_sampled: int | None
 
     @property
     def n(self) -> int: ...
@@ -204,6 +206,7 @@ class LocalBackend:
     variants = ("auto", "telescoped", "tree", "reference", "randomized")
     push_path: str | None = None
     lanes: list[int] | None = None
+    walks_sampled: int | None = None
 
     def __init__(
         self,
@@ -300,7 +303,7 @@ class LocalBackend:
         """One fused multi-query dispatch; returns ``(est, idx, vals,
         levels)`` (est for single_source kind, idx/vals for topk — the
         unused side is None; ``levels`` is the probe levels the step ran)
-        and sets ``push_path`` and ``lanes``.
+        and sets ``push_path``, ``lanes`` and ``walks_sampled``.
         Exactly one of ``keys`` ([Q] per-query streams) / ``key`` (scalar:
         legacy split semantics) is set.  ``live`` (None: every slot)
         leading slots are real queries and own all the lane columns; the
@@ -314,22 +317,21 @@ class LocalBackend:
             info=info, live=live,
         )
         if kind == "topk":
+            est = None
             idx, vals = multi_source_topk(
                 key, g, eg, us, k, self.params, **common
             )
-            with span(DISPATCH_FETCH):  # one transfer, answers and counts
-                idx, vals, levels, lanes = jax.device_get(
-                    (idx, vals, info["levels"], info["lanes"])
-                )
-            self.push_path, self.lanes = info["push_path"], lanes.tolist()
-            return None, idx, vals, int(levels)
-        est = multi_source(key, g, eg, us, self.params, **common)
-        with span(DISPATCH_FETCH):
-            est, levels, lanes = jax.device_get(
-                (est, info["levels"], info["lanes"])
-            )
+        else:
+            idx = vals = None
+            est = multi_source(key, g, eg, us, self.params, **common)
+        with span(DISPATCH_FETCH):  # one transfer, answers and counts
+            (est, idx, vals), (levels, lanes, walks) = jax.device_get((
+                (est, idx, vals),
+                (info["levels"], info["lanes"], info["walks_sampled"]),
+            ))
         self.push_path, self.lanes = info["push_path"], lanes.tolist()
-        return est, None, None, int(levels)
+        self.walks_sampled = int(walks)
+        return est, idx, vals, int(levels)
 
     # -- updates -------------------------------------------------------------
 
@@ -759,6 +761,7 @@ class ShardedBackend:
     name = "sharded"
     push_path = None  # the mesh probes keep their own pushes
     lanes = None  # and their own lane schedule
+    walks_sampled = None  # and their own walk pools
     supports_epoch = True
     variants = ("auto", "telescoped")
 

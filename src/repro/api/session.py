@@ -100,8 +100,10 @@ class EngineStats:
     already in the hub probe cache.  ``probe_levels`` totals the probe
     levels of the fused serve dispatches whose backend counts them;
     ``push_path`` names the push the latest of them ran
-    (``core.multisource.push_path``) and ``lanes`` the fewest lane columns
-    a live query of it owned (the query that sets its length).
+    (``core.multisource.push_path``), ``lanes`` the fewest lane columns
+    a live query of it owned (the query that sets its length) and
+    ``walks_sampled`` the walks its pool materialized (n_r for each live
+    query).
     """
 
     queries: int = 0
@@ -115,6 +117,7 @@ class EngineStats:
     probe_levels: int = 0
     push_path: str | None = None
     lanes: int | None = None
+    walks_sampled: int | None = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -553,7 +556,7 @@ class SimRankSession:
         self.stats.steps += 1
         self.stats.queries += spec.q
         self.stats.probe_levels += levels or 0
-        path, lanes = self._note_dispatch(levels, spec.q)
+        path, lanes, walks = self._note_dispatch(levels, spec.q)
         return ResultEnvelope(
             kind=spec.kind,
             node=spec.node,
@@ -566,23 +569,27 @@ class SimRankSession:
             probe_levels=levels,
             push_path=path,
             lanes=None if lanes is None else min(lanes),
+            walks_sampled=walks,
             **out,
         )
 
     def _note_dispatch(self, levels, live: int):
-        """``(push_path, lanes)`` of the fused dispatch that just ran: its
-        push and the lane columns each slot owned, kept in
-        ``stats.push_path`` and, as the fewest over the ``live`` leading
-        slots, ``stats.lanes``; ``(None, None)`` where the dispatch
+        """``(push_path, lanes, walks_sampled)`` of the fused dispatch
+        that just ran: its push, the lane columns each slot owned and the
+        walks its pool materialized, kept in ``stats.push_path``,
+        ``stats.walks_sampled`` and, as the fewest over the ``live``
+        leading slots, ``stats.lanes``; all None where the dispatch
         counted no levels (one-shot variants, the mesh backend)."""
         if levels is None:
-            return None, None
+            return None, None, None
         path = getattr(self.backend, "push_path", None)
         lanes = getattr(self.backend, "lanes", None)
+        walks = getattr(self.backend, "walks_sampled", None)
         self.stats.push_path = path
+        self.stats.walks_sampled = walks
         if lanes is not None:
             self.stats.lanes = min(lanes[:live])
-        return path, lanes
+        return path, lanes, walks
 
     def _multi_keys(self, spec: QuerySpec):
         """(key, keys) for a batched spec — exactly one of the two is set."""
@@ -654,6 +661,7 @@ class SimRankSession:
             probe_levels=envs[0].probe_levels,
             push_path=envs[0].push_path,
             lanes=envs[0].lanes,
+            walks_sampled=envs[0].walks_sampled,
         )
 
     def _serve_adaptive(
@@ -713,7 +721,7 @@ class SimRankSession:
                 streams.append(item[1])
                 cacheable.append(False)
         ver = self.version
-        levels = None  # summed over the rounds that dispatched
+        levels = walks = None  # summed over the rounds that dispatched
         path = lanes = None
         t0 = time.perf_counter()
         while True:
@@ -756,7 +764,8 @@ class SimRankSession:
                 if lv is not None:
                     levels = (levels or 0) + lv
                     self.stats.probe_levels += lv
-                    path, lanes = self._note_dispatch(lv, q)
+                    path, lanes, w = self._note_dispatch(lv, q)
+                    walks = None if w is None else (walks or 0) + w
                 self.stats.steps += 1
                 if r > 0:
                     self.stats.escalations += 1
@@ -787,6 +796,7 @@ class SimRankSession:
                 probe_levels=levels,
                 push_path=path,
                 lanes=None if lanes is None else lanes[i],
+                walks_sampled=walks,
             )
             if sp.kind == "single_source":
                 env.scores = scores
@@ -894,7 +904,7 @@ class SimRankSession:
             )
         self.stats.steps += 1
         self.stats.probe_levels += levels or 0
-        path, lanes = self._note_dispatch(
+        path, lanes, walks = self._note_dispatch(
             levels, len(batch) if live is None else live
         )
         ver = self.version
@@ -914,6 +924,7 @@ class SimRankSession:
                 probe_levels=levels,
                 push_path=path,
                 lanes=None if lanes is None else lanes[i],
+                walks_sampled=walks,
             )
             for i, item in enumerate(batch)
         ]

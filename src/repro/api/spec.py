@@ -125,9 +125,11 @@ class ResultEnvelope:
     path does not count them (one-shot legacy variants, fused epochs,
     the sharded backend).  ``push_path`` names the push those levels ran
     (``core.multisource.push_path``: ``csr_kernel``, ``coo_xla``, ...),
-    and ``lanes`` the lane columns this query owned in the dispatch (the
+    ``lanes`` the lane columns this query owned in the dispatch (the
     live queries of a short batch share all of them; for a batched spec,
-    the fewest any of its queries owned); both None where
+    the fewest any of its queries owned), and ``walks_sampled`` the walks
+    the dispatch's pool materialized, n_r for each live query (summed
+    over the rounds, as ``probe_levels`` is); all three None where
     ``probe_levels`` is.
 
     Field-superset of the legacy ``QueryResult`` — engine shims return
@@ -152,3 +154,4 @@ class ResultEnvelope:
     probe_levels: int | None = None
     push_path: str | None = None
     lanes: int | None = None
+    walks_sampled: int | None = None

@@ -158,7 +158,7 @@ def epoch_step(
         lambda state, b: _pair_apply(state, b), probe
     )
     (g2, eg2), applied, out = run((g, eg), batch, (keys, us, acc))
-    acc, est, idx, vals, _, _ = out  # levels and lanes are not reported here
+    acc, est, idx, vals = out[:4]  # the step's counters are not reported here
     return g2, eg2, applied, est, idx, vals
 
 

@@ -11,9 +11,10 @@ ONE compiled step per query batch:
   [n + 1, W] score buffer; each live query owns a contiguous block of about
   W/live lane columns (W/Q in a full batch; padding slots own none), so
   every push level is one SpMM dispatch for the whole batch;
-* **pooled walk sampling** — the entire walk pool (Q x n_r walks) is drawn
-  by one vmapped sampler call inside the same jit.  Per-chunk sampling pays
-  a large fixed dispatch cost (the ELL-table walk); pooling amortizes it;
+* **pooled walk sampling** — the walk pool (n_r walks for each of the
+  ``live`` real queries) is drawn inside the same jit before the first
+  level.  Per-chunk sampling pays a large fixed dispatch cost (the
+  ELL-table walk); pooling amortizes it;
 * **compacted walk scheduling** — instead of marching all lanes through the
   same global level p (leaving columns of short/dead walks pushing zeros for
   most levels), each lane column runs the telescoped probe of *its own* walk
@@ -197,6 +198,30 @@ def xla_level(g, scores, total, fin, u_p, u_prev, thr, *, cols, sqrt_c,
     return scores, total
 
 
+def live_walk_pool(keys, eg: EllGraph, us, live, *, n_r: int,
+                   max_len: int, sqrt_c: float) -> tuple[Array, Array]:
+    """The walk pool [Q * n_r, max_len] of a serve step whose first
+    ``live`` (traced int32) of the Q slots are real queries: slot i's
+    n_r walks, drawn from ``keys[i]`` and materialized one slot a loop
+    trip, fill rows ``[i * n_r, (i + 1) * n_r)``; a padding slot's rows
+    stay sentinel ``n`` (length 0).  Each slot's walks depend only on its
+    own key and source (``core.walks``), so its rows are bit for bit a
+    ``sample_walks_batch`` call's, whatever the live count.  Returns
+    ``(pool, walks_sampled)``, the latter the rows materialized (int32
+    scalar, ``live * n_r``)."""
+
+    def trip(i, pool):
+        cont, pick = walk_uniforms(
+            keys[i], n_r=n_r, max_len=max_len, sqrt_c=sqrt_c
+        )
+        walks = walks_from_uniforms(eg, us[i], cont, pick)
+        return jax.lax.dynamic_update_slice(pool, walks, (i * n_r, 0))
+
+    pool = jnp.full((us.shape[0] * n_r, max_len), eg.n, jnp.int32)
+    pool = jax.lax.fori_loop(0, live, trip, pool)
+    return pool, jnp.int32(live) * n_r
+
+
 def fused_serve_impl(
     keys: Array,  # [Q] typed PRNG keys, one stream per query
     g: Graph | EllGraph,
@@ -221,9 +246,9 @@ def fused_serve_impl(
     ``live`` (a traced int32 scalar, so one executable serves every count)
     marks the first ``live`` of the Q slots as real queries: they deal all
     W = Q * ``lanes_q`` lane columns among themselves (:func:`lane_owner`);
-    the padding slots behind them own no column and push none of their
-    walks (the pool still samples them), so a step's levels follow the
-    live queries' walks, not Q's.  Padding rows of ``est`` carry no
+    the padding slots behind them own no column and sample no walks
+    (:func:`live_walk_pool`), so a step's pool and levels follow the live
+    queries' walks, not Q's.  Padding rows of ``est`` carry no
     estimate.  ``live == Q`` is the full schedule: query i owns the
     columns ``[i * lanes_q, (i + 1) * lanes_q)``.
 
@@ -235,10 +260,11 @@ def fused_serve_impl(
     the fused CSR kernel over a dst-sorted view of the COO graph, built
     once here (sums in CSR order: 1e-5 of the XLA level); else the XLA
     level.  Returns
-    ``(acc, est, topk_idx, topk_vals, levels, lanes)``; the top-k outputs
-    are None when ``top_k == 0``.  ``levels`` (int32 scalar) counts the
-    probe levels the step ran, ``lanes`` (int32 [Q]) the lane columns
-    each slot owned.
+    ``(acc, est, topk_idx, topk_vals, levels, lanes, walks_sampled)``; the
+    top-k outputs are None when ``top_k == 0``.  ``levels`` (int32 scalar)
+    counts the probe levels the step ran, ``lanes`` (int32 [Q]) the lane
+    columns each slot owned, ``walks_sampled`` (int32 scalar) the walks
+    the pool materialized, ``live * n_r``.
     """
     n = eg.n
     q = us.shape[0]
@@ -254,16 +280,10 @@ def fused_serve_impl(
         else jnp.float32
     )
 
-    # --- walk pool ----------------------------------------------------------
-    # All Q x n_r walks, from per-(walk, step) uniforms drawn up front
-    # (bit-identical to a pooled sample_walks_batch call).  Padding slots
-    # draw theirs too, and no column ever takes them.
-    cont, pick = jax.vmap(
-        lambda k: walk_uniforms(k, n_r=n_r, max_len=max_len, sqrt_c=sqrt_c)
-    )(keys)
-    pool = jax.vmap(lambda u1, c, p: walks_from_uniforms(eg, u1, c, p))(
-        us, cont, pick
-    ).reshape(q * n_r, max_len)
+    # --- walk pool: the live slots' n_r walks each ----------------------
+    pool, walks_sampled = live_walk_pool(
+        keys, eg, us, live, n_r=n_r, max_len=max_len, sqrt_c=sqrt_c
+    )
     pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
 
     # --- one probe level: deposit + inject + prune + push + exclude -------
@@ -358,8 +378,8 @@ def fused_serve_impl(
     if top_k > 0:
         masked = est.at[jnp.arange(q), us].set(-jnp.inf)
         vals, idx = jax.lax.top_k(masked, top_k)
-        return acc, est, idx, vals, levels, lanes
-    return acc, est, None, None, levels, lanes
+        return acc, est, idx, vals, levels, lanes, walks_sampled
+    return acc, est, None, None, levels, lanes, walks_sampled
 
 
 # The standalone jitted entry point.  ``fused_serve_impl`` stays un-jitted so
@@ -401,14 +421,15 @@ def _serve(
     :func:`multi_source_topk`; returns ``(est, idx, vals)`` on the device
     and, when ``info`` is a dict, puts the step's probe-level count (int32
     scalar) in ``info["levels"]``, its :func:`push_path` in
-    ``info["push_path"]`` and the lane columns each slot owned (int32
-    [Q], from the step) in ``info["lanes"]``.  ``live`` None serves every
-    slot."""
+    ``info["push_path"]``, the lane columns each slot owned (int32
+    [Q], from the step) in ``info["lanes"]`` and the walks its pool
+    materialized (int32 scalar) in ``info["walks_sampled"]``.  ``live``
+    None serves every slot."""
     us = jnp.asarray(us, jnp.int32)
     q = int(us.shape[0])
     lanes_q = max(1, lanes // q)
     acc = jnp.zeros((q, g.n), jnp.float32)
-    _, est, idx, vals, levels, owned = _fused_serve(
+    _, est, idx, vals, levels, owned, walks = _fused_serve(
         _query_keys(key, keys, q), g, eg, us, acc,
         np.int32(q if live is None else live),
         n_r=int(n_r or params.n_r),
@@ -426,6 +447,7 @@ def _serve(
         info["levels"] = levels
         info["push_path"] = push_path(g, q * lanes_q, use_kernel=use_kernel)
         info["lanes"] = owned
+        info["walks_sampled"] = walks
     return est, idx, vals
 
 
@@ -458,10 +480,12 @@ def multi_source(
     batch-vs-serial determinism; otherwise ``key`` is split into Q streams.
     A dict passed as ``info`` receives ``"levels"``: the number of probe
     levels the step ran, as an int32 device scalar, ``"push_path"``: the
-    push its levels ran (:func:`push_path`), and ``"lanes"``: the lane
-    columns each query owned, as an int32 [Q] device array.  ``live``
+    push its levels ran (:func:`push_path`), ``"lanes"``: the lane
+    columns each query owned, as an int32 [Q] device array, and
+    ``"walks_sampled"``: the walks the pool materialized (``live *
+    n_r``), as an int32 device scalar.  ``live``
     serves only the first ``live`` queries of ``us``: they share every
-    lane column and the rest are padding with no estimate
+    lane column and the rest are padding with no walks and no estimate
     (``fused_serve_impl``); the count is data, so one compiled step
     serves every ``live`` for a batch shape.
     """

@@ -241,6 +241,8 @@ def envelope_to_wire(env, **extra) -> dict:
         out["push_path"] = env.push_path
     if env.lanes is not None:
         out["lanes"] = int(env.lanes)
+    if env.walks_sampled is not None:
+        out["walks_sampled"] = int(env.walks_sampled)
     out.update({k: _jsonable(v) for k, v in extra.items()})
     return out
 

@@ -8,6 +8,8 @@ Covers the tentpole contracts:
   (the engine's batched ``drain()`` == serial serving property);
 * the live queries of a short batch share every lane column (padding slots
   own none), and a full batch keeps the lane-block schedule bit for bit;
+* the walk pool holds the live slots' walks only, each bit for bit what
+  the all-slots pool held, so every live answer is unchanged;
 * COO push, ELL push and the Pallas kernel path agree.
 """
 import numpy as np
@@ -23,7 +25,10 @@ from repro.core import (
     single_source,
     simrank_power,
 )
-from repro.core.multisource import lane_columns, lane_refill
+from repro.core import multisource
+from repro.core.multisource import (
+    fused_serve_impl, lane_columns, lane_refill, live_walk_pool,
+)
 from repro.core.probe import probe_walks_telescoped
 from repro.core.walks import sample_walks_batch
 from repro.graph import ell_from_edges, graph_from_edges, powerlaw_graph
@@ -191,6 +196,58 @@ def test_live_one_step_runs_its_own_levels(small_powerlaw, key):
     held = int(np.maximum((np.asarray(pool) < n).sum(-1) - 1, 1).sum())
     assert int(lone["levels"]) <= -(-held // 64) + params.max_len
     assert int(lone["levels"]) < int(padded["levels"])
+
+
+def _all_slots_pool(keys, eg, us, live, *, n_r, max_len, sqrt_c):
+    """Every slot's n_r walks, padding slots' too: the pool the step
+    sampled before it sampled the live slots only."""
+    pool = sample_walks_batch(keys, eg, us, n_r=n_r, max_len=max_len,
+                              sqrt_c=sqrt_c)
+    return pool.reshape(-1, max_len), jnp.int32(us.shape[0] * n_r)
+
+
+@pytest.mark.parametrize("live", [1, 2, 4])
+def test_pool_samples_the_live_slots_only(small_powerlaw, key, monkeypatch,
+                                          live):
+    """The step materializes n_r walks for each live slot and none for
+    the padding: each live slot's rows are ``sample_walks_batch``'s bit
+    for bit, padding rows are sentinel, and every live estimate, and the
+    level count, equal the same step over the all-slots pool bit for
+    bit."""
+    g, eg, n = small_powerlaw["g"], small_powerlaw["eg"], small_powerlaw["n"]
+    q, n_r = 4, 150
+    params = make_params(n, c=0.6, eps_a=0.2, n_r_override=n_r)
+    us = np.argsort(-np.asarray(g.in_deg))[:q].astype(np.int32)
+    pus, pkeys = _padded(us, jax.random.split(key, q), live)
+    walk = dict(n_r=n_r, max_len=params.max_len, sqrt_c=params.sqrt_c)
+
+    pool, sampled = live_walk_pool(pkeys, eg, pus, jnp.int32(live), **walk)
+    assert int(sampled) == live * n_r
+    pool = np.asarray(pool).reshape(q, n_r, params.max_len)
+    ref = sample_walks_batch(pkeys[:live], eg, pus[:live], **walk)
+    assert np.array_equal(pool[:live], np.asarray(ref))
+    assert (pool[live:] == n).all()
+
+    static = dict(
+        n_r=n_r, lanes_q=64 // q, max_len=params.max_len,
+        sqrt_c=params.sqrt_c, eps_p=params.eps_p, eps_t=params.eps_t,
+        truncation_shift=params.truncation_shift, use_kernel=False,
+        top_k=0,
+    )
+
+    def step():  # traced afresh, so it picks up the pool in place now
+        acc = jnp.zeros((q, n), jnp.float32)
+        return jax.jit(lambda *a: fused_serve_impl(*a, **static))(
+            pkeys, g, eg, pus, acc, jnp.int32(live)
+        )
+
+    _, est, _, _, levels, _, walks = step()
+    assert int(walks) == live * n_r
+    monkeypatch.setattr(multisource, "live_walk_pool", _all_slots_pool)
+    _, ref_est, _, _, ref_levels, _, ref_walks = step()
+    assert int(ref_walks) == q * n_r
+    assert int(levels) == int(ref_levels)
+    assert np.array_equal(np.asarray(est)[:live], np.asarray(ref_est)[:live])
 
 
 def test_padding_slots_claim_no_column():

@@ -163,6 +163,7 @@ def test_microbatch_parity_and_fusion(service_graph):
                 np.asarray(r["topk_scores"], np.float32), vals[j]
             )
             assert r["lanes"] == lanes[j]
+            assert r["walks_sampled"] == kw["live"] * 64
             tk = ref.submit(QuerySpec(
                 kind="topk", node=i, k=5, budget_walks=64, key=key,
             ))
@@ -215,6 +216,23 @@ def test_single_source_roundtrip(service_graph):
         assert np.array_equal(
             np.asarray(r["scores"], np.float32), np.asarray(tk.envelope.scores)
         )
+    finally:
+        stop_server(server, thread)
+
+
+def test_walks_sampled_reaches_answer_and_stats(service_graph):
+    """A lone query's dispatch samples its own n_r walks, not batch_q
+    times that: the answer and the tenant's ``/stats`` both say so."""
+    handle, n = service_graph
+    svc, server, thread = _live_server(handle)
+    host, port = server.server_address
+    try:
+        with ServiceClient(host, port) as cl:
+            r = cl.query(node=3, kind="topk", k=5, budget_walks=40, seed=3)
+            stats = cl.stats()
+        assert r["batch_size"] == 1
+        assert r["walks_sampled"] == 40
+        assert stats["tenants"]["default"]["walks_sampled"] == 40
     finally:
         stop_server(server, thread)
 

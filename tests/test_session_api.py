@@ -199,6 +199,30 @@ def test_short_batch_reports_its_lanes(small_powerlaw):
     assert lone.push_path == "coo_xla"
 
 
+def test_short_batch_reports_its_walks_sampled(small_powerlaw):
+    """A drained dispatch's pool holds n_r walks for each live query and
+    none for the padding: its answers, the wire and
+    ``stats.walks_sampled`` report live * n_r."""
+    from repro.serving.protocol import envelope_to_wire
+
+    h = GraphHandle(g=small_powerlaw["g"], eg=small_powerlaw["eg"])
+    sess = SimRankSession(h, c=0.6, eps_a=0.2, top_k=5, batch_q=4, seed=0,
+                          walk_chunk=64)
+    assert sess.stats.walks_sampled is None
+    nodes = np.argsort(-np.asarray(h.g.in_deg))[:6].astype(int)
+    for u in nodes:
+        sess.submit(int(u))
+    res = sess.drain(budget_walks=48)
+    assert [r.walks_sampled for r in res] == [4 * 48] * 4 + [2 * 48] * 2
+    assert sess.stats.walks_sampled == 2 * 48
+    assert envelope_to_wire(res[5])["walks_sampled"] == 2 * 48
+    assert sess.backend.walks_sampled == 2 * 48
+    sess.submit(int(nodes[0]))
+    (lone,) = sess.drain(budget_walks=48)
+    assert lone.walks_sampled == sess.stats.walks_sampled == 48
+    assert sess.stats.as_dict()["walks_sampled"] == 48
+
+
 def test_drain_cuts_batches_at_group_change(small_powerlaw):
     """Specs with different (kind, k, budget) never share a dispatch."""
     h = GraphHandle(g=small_powerlaw["g"], eg=small_powerlaw["eg"])

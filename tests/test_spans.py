@@ -174,7 +174,7 @@ def test_counter_leaves_answers_bitwise_unchanged(handle, top_k):
         return jnp.zeros((q, handle.n), jnp.float32)
 
     live = jnp.int32(q)
-    _, est, idx, vals, levels, _ = _fused_serve(
+    _, est, idx, vals, levels, _, _ = _fused_serve(
         keys, g, eg, us, acc(), live, **static
     )
     without = jax.jit(lambda *a: fused_serve_impl(*a, **static)[:4])
