@@ -18,7 +18,7 @@ def emit(name: str, us_per_call: float, derived: str = "") -> None:
     print(row, flush=True)
 
 
-PROMOTED = ("serve", "dynamic", "abserror", "service", "stream", "kernels")
+PROMOTED = ("serve", "dynamic", "abserror", "service", "stream")
 
 
 def write_json(path: str, *, quick: bool, suites: list[str]) -> None:

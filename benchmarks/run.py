@@ -7,9 +7,8 @@ for the serial vs fused-batched drain) when the serve suite runs,
 update->queryable latency) when the dynamic suite runs, and
 ``BENCH_abserror.json`` (the adaptive-controller epsilon sweep: walks used,
 oracle max-abs-error vs certified bound, precision@10, walks saved vs the
-flat budget) when the abserror suite runs, and ``BENCH_kernels.json`` (the
-fused lane-probe kernel vs the XLA lane-level oracle with roofline records)
-when the kernels suite runs — each also carrying every emitted row.  ``--full`` runs paper-scale sweeps; default (``--quick``) is
+flat budget) when the abserror suite runs — each also carrying every
+emitted row.  ``--full`` runs paper-scale sweeps; default (``--quick``) is
 the CPU-quick profile.
 """
 from __future__ import annotations
@@ -30,7 +29,7 @@ def main() -> None:
                     help="CPU-quick profile (the default; negates --full)")
     ap.add_argument("--only", default=None,
                     help="comma list: serve,service,abserror,topk,large,"
-                         "dynamic,kernels,stream")
+                         "dynamic,stream")
     ap.add_argument("--backend", choices=("local", "sharded"), default="local",
                     help="forwarded to suites that take it (serve, dynamic, "
                          "service, stream): 'sharded' adds the mesh-backend "
@@ -49,7 +48,6 @@ def main() -> None:
     from benchmarks import (
         bench_abserror,
         bench_dynamic,
-        bench_kernels,
         bench_large,
         bench_serve,
         bench_service,
@@ -65,15 +63,13 @@ def main() -> None:
         topk=bench_topk.run,
         large=bench_large.run,
         dynamic=bench_dynamic.run,
-        kernels=bench_kernels.run,
         stream=bench_stream.run,
     )
     takes_backend = {"serve", "dynamic", "service", "stream"}  # mesh legs
     # suites that must fill RESULTS[name]; abserror is structured too — it
     # used to print CSV rows and silently drop its metrics, so the
     # accuracy-gate job had nothing machine-readable to enforce
-    structured = {"serve", "dynamic", "abserror", "service", "stream",
-                  "kernels"}
+    structured = {"serve", "dynamic", "abserror", "service", "stream"}
     chosen = args.only.split(",") if args.only else list(suites)
     unknown = [name for name in chosen if name not in suites]
     if unknown:
@@ -115,8 +111,6 @@ def main() -> None:
             write_json("BENCH_abserror.json", quick=quick, suites=chosen)
         if "stream" in chosen:
             write_json("BENCH_stream.json", quick=quick, suites=chosen)
-        if "kernels" in chosen:
-            write_json("BENCH_kernels.json", quick=quick, suites=chosen)
 
 
 if __name__ == "__main__":

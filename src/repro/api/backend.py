@@ -179,7 +179,6 @@ class Backend(Protocol):
         n_r: int,
         top_k: int,
         lanes: int | None = None,
-        use_kernel: bool | None = None,
     ) -> tuple: ...
 
 
@@ -214,21 +213,12 @@ class LocalBackend:
         *,
         params: ProbeSimParams,
         walk_chunk: int = 256,
-        use_kernel: bool = False,
-        kernel_dtype: str = "float32",
     ):
         if not isinstance(handle, GraphHandle):
             raise TypeError("LocalBackend takes a GraphHandle")
-        if kernel_dtype not in ("float32", "bfloat16"):
-            raise ValueError(
-                f"kernel_dtype must be 'float32' or 'bfloat16', "
-                f"got {kernel_dtype!r}"
-            )
         self.handle = handle
         self.params = params
         self.walk_chunk = walk_chunk
-        self.use_kernel = use_kernel
-        self.kernel_dtype = kernel_dtype
         self._hubs: tuple | None = None  # ((version, percentile), frozenset)
 
     # -- snapshot state ------------------------------------------------------
@@ -287,12 +277,12 @@ class LocalBackend:
         if spec.kind == "single_source":
             est = single_source(
                 key, g, eg, spec.node, p, variant=variant,
-                walk_chunk=self.walk_chunk, use_kernel=self.use_kernel,
+                walk_chunk=self.walk_chunk,
             )
             return dict(scores=np.asarray(est))
         idx, vals = topk(
             key, g, eg, spec.node, spec.k, p, variant=variant,
-            walk_chunk=self.walk_chunk, use_kernel=self.use_kernel,
+            walk_chunk=self.walk_chunk,
         )
         return dict(topk_nodes=np.asarray(idx), topk_scores=np.asarray(vals))
 
@@ -312,9 +302,7 @@ class LocalBackend:
         us = jnp.asarray(us, jnp.int32)
         info: dict = {}
         common = dict(
-            lanes=self.walk_chunk, n_r=n_r, keys=keys,
-            use_kernel=self.use_kernel, kernel_dtype=self.kernel_dtype,
-            info=info, live=live,
+            lanes=self.walk_chunk, n_r=n_r, keys=keys, info=info, live=live
         )
         if kind == "topk":
             est = None
@@ -365,7 +353,6 @@ class LocalBackend:
         n_r: int,
         top_k: int,
         lanes: int | None = None,
-        use_kernel: bool | None = None,
     ) -> tuple:
         """One fused local epoch: ``core.epoch.epoch_step`` over the owned
         mirrors (donated; the handle is replaced with the post-epoch
@@ -390,9 +377,6 @@ class LocalBackend:
             eps_p=p.eps_p,
             eps_t=p.eps_t,
             truncation_shift=p.truncation_shift,
-            use_kernel=(
-                self.use_kernel if use_kernel is None else use_kernel
-            ),
             top_k=top_k,
         )
         if top_k:
@@ -776,7 +760,6 @@ class ShardedBackend:
         probe: str = "spmd",
         edge_chunks: int = 4,
         capacity_per_shard: int | None = None,
-        use_kernel: bool = False,
         frontier_dtype: str = "float32",
     ):
         if probe not in ("spmd", "ring"):
@@ -800,7 +783,6 @@ class ShardedBackend:
         self.walk_chunk = int(walk_chunk)
         self.probe = probe
         self.edge_chunks = int(edge_chunks)
-        self.use_kernel = bool(use_kernel)
         self.frontier_dtype = frontier_dtype
         if mesh is None:
             ndev = len(jax.devices())
@@ -958,7 +940,6 @@ class ShardedBackend:
         n_r: int,
         top_k: int,
         lanes: int | None = None,
-        use_kernel: bool | None = None,
     ) -> tuple:
         """One fused MESH epoch: shard_map update apply + distributed probe
         in a single compiled dispatch against the carried device mirror
@@ -969,10 +950,9 @@ class ShardedBackend:
         """
         st = self._epoch_graph_state()
         q = 0 if us is None else len(us)
-        uk = self.use_kernel if use_kernel is None else bool(use_kernel)
         cfg = (
             q, n_r if q else 0, top_k if q else 0,
-            bool(batch.has_deletes), st.capacity, st.k_max, uk,
+            bool(batch.has_deletes), st.capacity, st.k_max,
         )
         step = self._epoch_steps.get(cfg)
         if step is None:
@@ -984,7 +964,6 @@ class ShardedBackend:
                 eps_t=p.eps_t, truncation_shift=p.truncation_shift,
                 walk_chunk=self.walk_chunk, edge_chunks=self.edge_chunks,
                 has_deletes=bool(batch.has_deletes),
-                use_kernel=uk,
             )
             self._epoch_steps[cfg] = step
         # host copies of the op stream BEFORE the dispatch (the replay
@@ -1059,8 +1038,7 @@ class ShardedBackend:
             ring_band = rg.src_sh.shape
         cfg = (
             q, int(k), int(n_r), wq, self.probe,
-            st.capacity, st.k_max, ring_band,
-            self.use_kernel, self.frontier_dtype,
+            st.capacity, st.k_max, ring_band, self.frontier_dtype,
         )
         step = self._steps.get(cfg)
         if step is None:
@@ -1071,7 +1049,6 @@ class ShardedBackend:
                 max_len=p.max_len, sqrt_c=p.sqrt_c, eps_p=p.eps_p,
                 eps_t=p.eps_t, truncation_shift=p.truncation_shift,
                 probe=self.probe,
-                use_kernel=self.use_kernel,
                 frontier_dtype=self.frontier_dtype,
             )
             self._steps[cfg] = step

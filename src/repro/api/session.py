@@ -272,7 +272,6 @@ class SimRankSession:
         batch_q: int = 8,
         update_batch: int = 64,
         auto_regrow: bool = True,
-        use_kernel: bool = False,
         own_graph: bool = True,
         backend: str | Backend = "local",
         shards: int | None = None,
@@ -309,7 +308,6 @@ class SimRankSession:
         self.batch_q = batch_q
         self.update_batch = update_batch
         self.auto_regrow = auto_regrow
-        self.use_kernel = use_kernel
         if isinstance(backend, str):
             if backend == "local":
                 if shards is not None or mesh is not None or backend_options:
@@ -326,7 +324,7 @@ class SimRankSession:
                 )
                 self.backend: Backend = LocalBackend(
                     self.handle, params=self.params,
-                    walk_chunk=walk_chunk, use_kernel=use_kernel,
+                    walk_chunk=walk_chunk,
                 )
             elif backend == "sharded":
                 self.params = make_params(
@@ -334,8 +332,7 @@ class SimRankSession:
                 )
                 self.backend = ShardedBackend(
                     handle, params=self.params, shards=shards, mesh=mesh,
-                    walk_chunk=walk_chunk, use_kernel=use_kernel,
-                    **(backend_options or {}),
+                    walk_chunk=walk_chunk, **(backend_options or {}),
                 )
                 # the sharded state owns a partitioned copy of the edges;
                 # the constructor handle is not kept (it would go stale on
@@ -1177,7 +1174,7 @@ class SimRankSession:
             applied, est, idx, vals = self.backend.epoch_batch(
                 batch, us, keys,
                 n_r=n_r, top_k=tk,
-                lanes=self.walk_chunk, use_kernel=self.use_kernel,
+                lanes=self.walk_chunk,
             )
         else:
             live_q, qs, spec0 = 0, [], None
@@ -1185,7 +1182,7 @@ class SimRankSession:
             applied, est, idx, vals = self.backend.epoch_batch(
                 batch, None, None,
                 n_r=n_r, top_k=0,
-                lanes=self.walk_chunk, use_kernel=self.use_kernel,
+                lanes=self.walk_chunk,
             )
         applied = np.asarray(applied)[: len(ops)]
         dt = time.time() - t0

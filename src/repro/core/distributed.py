@@ -266,8 +266,7 @@ def lane_probe_block(
     ``level_fn(scores, total, fin, u_p, u_prev, thr) -> (scores, total)``
     executes one full probe level — deposit of finishing columns, unit
     injection at ``u_p``, pruning at ``thr``, the renormalized push, and
-    the ``u_prev`` exclusion — either as the XLA composition
-    (``lane_level_xla``) or fused on-chip (``kernels/lane_probe``).
+    the ``u_prev`` exclusion (``lane_level_xla`` over the caller's push).
     ``sentinel`` is the pool's walk-end marker; sentinel ids either hit a
     padding row (whose pushed mass is sliced away by the caller's ``[:n]``)
     or nothing.
@@ -337,8 +336,6 @@ def probe_lanes_sharded(
     eps_p: float,
     sentinel: int,
     edge_chunk: int = 2048,
-    use_kernel: bool = False,
-    in_nbrs: Array | None = None,
     frontier_dtype: str = "float32",
 ) -> Array:
     """Lane-batched telescoped probe, all-gather push; returns [n_pad, W].
@@ -361,11 +358,7 @@ def probe_lanes_sharded(
     live chunk gather a garbage row but scatter into the dropped segment
     ``rows`` (their dst is the sentinel), so no zero-row append is needed.
 
-    ``use_kernel=True`` replaces the COO chunk loop with the fused Pallas
-    lane-probe level (``kernels/lane_probe``) gathering from the all-gathered
-    frontier through the row-sharded ELL table ``in_nbrs`` ([n_pad, k_max],
-    sentinel ``sentinel``) — deposit/inject/prune/push/exclude in one pass
-    per level.  ``frontier_dtype="bfloat16"`` halves the per-level
+    ``frontier_dtype="bfloat16"`` halves the per-level
     all_gather wire volume (the dominant collective, ROADMAP): the frontier
     is rounded to bf16 and bitcast to uint16 for the exchange (the same
     wire trick as ``core/ring.py``), then widened back — accumulation,
@@ -418,51 +411,36 @@ def probe_lanes_sharded(
             ).astype(jnp.float32)
         return jax.lax.all_gather(scores, "model", axis=0, tiled=True)
 
-    def local(src_b, dst_b, cnt_b, w_l, pool_l, plen_l, ell_l=None):
+    def local(src_b, dst_b, cnt_b, w_l, pool_l, plen_l):
         # src_b/dst_b [1, e_pad]; cnt_b [1]; w_l [rows]; pool replicated
         me = jax.lax.axis_index("model")
         row0 = me * rows
+        # clip into the real row range: sentinel srcs read a garbage
+        # row whose message lands in the dropped segment (sentinel dst)
+        sb = src_b[0].clip(0, n_pad - 1)
+        db = (dst_b[0] - row0).clip(0, rows)
+        n_chunks = (cnt_b[0] + ch - 1) // ch
 
-        if use_kernel:
-            from repro.kernels.lane_probe.ops import lane_probe_level
+        def push_block(scores):
+            full = _exchange(scores)
 
-            def level_fn(scores, total, fin, u_p, u_prev, thr):
-                # deposit reads the exact local block; only the gathered
-                # frontier rides the (possibly bf16) wire
-                full = _exchange(scores)
-                return lane_probe_level(
-                    ell_l, w_l, full, scores, total,
-                    fin, u_p, u_prev, thr,
-                    row0=row0, tab0=row0, n_live=sentinel,
-                    prune=eps_p > 0.0,
+            def chunk(i, acc):
+                s_c = jax.lax.dynamic_slice(sb, (i * ch,), (ch,))
+                d_c = jax.lax.dynamic_slice(db, (i * ch,), (ch,))
+                return acc + jax.ops.segment_sum(
+                    full[s_c], d_c, num_segments=rows + 1
                 )
-        else:
-            # clip into the real row range: sentinel srcs read a garbage
-            # row whose message lands in the dropped segment (sentinel dst)
-            sb = src_b[0].clip(0, n_pad - 1)
-            db = (dst_b[0] - row0).clip(0, rows)
-            n_chunks = (cnt_b[0] + ch - 1) // ch
 
-            def push_block(scores):
-                full = _exchange(scores)
-
-                def chunk(i, acc):
-                    s_c = jax.lax.dynamic_slice(sb, (i * ch,), (ch,))
-                    d_c = jax.lax.dynamic_slice(db, (i * ch,), (ch,))
-                    return acc + jax.ops.segment_sum(
-                        full[s_c], d_c, num_segments=rows + 1
-                    )
-
-                acc0 = jax.lax.pcast(
-                    jnp.zeros((rows + 1, scores.shape[1]), jnp.float32),
-                    "model", to="varying",
-                )
-                acc = jax.lax.fori_loop(0, n_chunks, chunk, acc0)[:rows]
-                return acc * w_l[:, None]
-
-            level_fn = lane_level_xla(
-                push_block, row0=row0, rows=rows, w=q * wq, eps_p=eps_p
+            acc0 = jax.lax.pcast(
+                jnp.zeros((rows + 1, scores.shape[1]), jnp.float32),
+                "model", to="varying",
             )
+            acc = jax.lax.fori_loop(0, n_chunks, chunk, acc0)[:rows]
+            return acc * w_l[:, None]
+
+        level_fn = lane_level_xla(
+            push_block, row0=row0, rows=rows, w=q * wq, eps_p=eps_p
+        )
 
         return lane_probe_block(
             level_fn, pool_l, plen_l,
@@ -470,29 +448,16 @@ def probe_lanes_sharded(
             max_len=max_len, sqrt_c=sqrt_c, eps_p=eps_p, sentinel=sentinel,
         )
 
-    in_specs = [
-        P("model", None), P("model", None), P("model"), P("model"),
-        P(), P(),
-    ]
-    args = [src_sh, dst_sh, counts, w_full, pool, pool_len]
-    if use_kernel:
-        if in_nbrs is None:
-            raise ValueError("use_kernel=True needs the row-sharded ELL "
-                             "table (in_nbrs)")
-        in_specs.append(P("model", None))
-        args.append(in_nbrs)
-
     fn = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=tuple(in_specs),
+        in_specs=(P("model", None), P("model", None), P("model"),
+                  P("model"), P(), P()),
         out_specs=P("model", None),
         # fully manual; inputs and compute replicate over the data axes
         axis_names=set(mesh.axis_names),
-        # the Pallas interpreter cannot slice blocks of varying operands
-        check_vma=not use_kernel,
     )
-    return fn(*args)
+    return fn(src_sh, dst_sh, counts, w_full, pool, pool_len)
 
 
 def _row_pad(sg: ShardedGraph) -> int:

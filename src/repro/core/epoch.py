@@ -100,7 +100,6 @@ def epoch_pipeline(apply_stage, probe_stage):
         "eps_p",
         "eps_t",
         "truncation_shift",
-        "use_kernel",
         "top_k",
     ),
     # g/eg are donated so the update scan writes the graph buffers in place
@@ -125,7 +124,6 @@ def epoch_step(
     eps_p: float,
     eps_t: float,
     truncation_shift: bool,
-    use_kernel: bool,
     top_k: int,
 ):
     """One fused LOCAL epoch: apply the update batch, serve the query batch.
@@ -150,7 +148,6 @@ def epoch_step(
             eps_p=eps_p,
             eps_t=eps_t,
             truncation_shift=truncation_shift,
-            use_kernel=use_kernel,
             top_k=top_k,
         )
 
@@ -489,7 +486,6 @@ def make_sharded_epoch_step(
     walk_chunk: int,
     edge_chunks: int,
     has_deletes: bool,
-    use_kernel: bool = False,
 ):
     """Compile the mesh epoch step for one (geometry, Q, n_r, k) config.
 
@@ -512,16 +508,8 @@ def make_sharded_epoch_step(
     fix, top-k) matches ``fused_serve_impl``'s conventions, so
     local-vs-sharded epoch parity under shared keys is tolerance-bounded
     by float summation order alone.
-
-    ``use_kernel=True`` routes the query stage through the compacted lane
-    probe with the fused Pallas level kernel (``probe_lanes_sharded`` with
-    ``use_kernel``) instead of the chunk-scanned ``probe_walks_sharded`` —
-    the kernel cannot run inside the auto-partitioned scan region, but the
-    fully-manual lane probe hosts it directly; ``walk_chunk`` becomes the
-    per-query lane width.  Estimates match the default path to float
-    summation order (the paths schedule pushes differently by design).
     """
-    from repro.core.distributed import probe_lanes_sharded, probe_walks_sharded
+    from repro.core.distributed import probe_walks_sharded
     from repro.core.walks import sample_walks_batch
 
     n, n_pad = st.n, st.n_pad
@@ -552,34 +540,6 @@ def make_sharded_epoch_step(
         pool = sample_walks_batch(
             keys, eg_view, us, n_r=n_r, max_len=max_len, sqrt_c=sqrt_c
         )  # [Q, n_r, L]
-        if use_kernel:
-            # fused Pallas lane probe (cannot trace into the auto-region
-            # scan below — shard_map hosts it instead); walk_chunk becomes
-            # the per-query lane width
-            wq = cc
-            pool_f = pool.reshape(q * n_r, max_len)
-            pool_len = (pool_f < n).sum(axis=1).astype(jnp.int32)
-            d = state2.in_deg.astype(jnp.float32)
-            w_full = (
-                jnp.where(d > 0, 1.0 / jnp.maximum(d, 1.0), 0.0) * sqrt_c
-            )
-            total = probe_lanes_sharded(
-                state2.src_sh, state2.dst_sh, state2.counts, w_full,
-                pool_f, pool_len, mesh,
-                n_pad=n_pad, rows=st.rows, q=q, wq=wq, n_r=n_r,
-                max_len=max_len, sqrt_c=sqrt_c, eps_p=eps_p, sentinel=n,
-                use_kernel=True, in_nbrs=state2.in_nbrs,
-            )  # [n_pad, W]
-            counts = total[:n].reshape(n, q, wq).sum(axis=2).T
-            est = counts / n_r
-            if truncation_shift:
-                est = jnp.where(est > 0, est + eps_t / 2, est)
-            est = est.at[jnp.arange(q), us].set(1.0)
-            if top_k > 0:
-                masked = est.at[jnp.arange(q), us].set(-jnp.inf)
-                vals, idx = jax.lax.top_k(masked, top_k)
-                return est, idx, vals
-            return est, None, None
         if n_r_pad != n_r:
             pool = jnp.concatenate(
                 [pool,
@@ -663,7 +623,6 @@ def make_sharded_serve_step(
     eps_t: float,
     truncation_shift: bool,
     probe: str = "spmd",
-    use_kernel: bool = False,
     frontier_dtype: str = "float32",
 ):
     """Compile the mesh SERVE step for one (geometry, Q, n_r, k) config.
@@ -685,12 +644,6 @@ def make_sharded_serve_step(
     (same ``lanes_q``) and matches the local path to float-summation
     tolerance.
 
-    ``use_kernel=True`` runs every probe level through the fused Pallas
-    lane-probe kernel (per-shard ELL gather off the all-gathered frontier
-    for spmd; fused level prologue for ring).  The spmd kernel path shares
-    the local kernel path's push-weight formulation and gather reduction
-    order, so a sharded kernel serve is BITWISE-equal to a local
-    ``use_kernel=True`` serve under shared keys (fp32).
     ``frontier_dtype="bfloat16"`` (spmd only) halves the per-level
     all_gather wire volume; parity vs fp32 is ~1e-3 on estimates.
     """
@@ -713,13 +666,7 @@ def make_sharded_serve_step(
         ).reshape(q * n_r, max_len)
         pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
         d = state.in_deg.astype(jnp.float32)
-        if use_kernel and probe == "spmd":
-            # the local kernel path's formulation (inv_in_deg * sqrt_c):
-            # same rounding per weight, so sharded-kernel == local-kernel
-            # serves are bitwise under shared keys
-            w_full = jnp.where(d > 0, 1.0 / jnp.maximum(d, 1.0), 0.0) * sqrt_c
-        else:
-            w_full = jnp.where(d > 0, sqrt_c / jnp.maximum(d, 1.0), 0.0)
+        w_full = jnp.where(d > 0, sqrt_c / jnp.maximum(d, 1.0), 0.0)
         if probe == "ring":
             from repro.core.ring import probe_lanes_ring
 
@@ -727,7 +674,6 @@ def make_sharded_serve_step(
                 ring_src, ring_dst, w_full, pool, pool_len, mesh,
                 rows=rows, shards=S, q=q, wq=wq, n_r=n_r,
                 max_len=max_len, sqrt_c=sqrt_c, eps_p=eps_p, sentinel=n,
-                use_kernel=use_kernel,
             )
         else:
             total = probe_lanes_sharded(
@@ -735,7 +681,6 @@ def make_sharded_serve_step(
                 pool, pool_len, mesh,
                 n_pad=n_pad, rows=rows, q=q, wq=wq, n_r=n_r,
                 max_len=max_len, sqrt_c=sqrt_c, eps_p=eps_p, sentinel=n,
-                use_kernel=use_kernel, in_nbrs=state.in_nbrs,
                 frontier_dtype=frontier_dtype,
             )  # [n_pad, W]
         acc = total[:n].reshape(n, q, wq).sum(axis=2).T  # [Q, n]

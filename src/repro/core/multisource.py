@@ -23,10 +23,14 @@ ONE compiled step per query batch:
   column is refilled with the next walk from its query's pool partition.
   Total push work drops from ``n_r * (max_len - 1)`` column-levels per query
   to ``n_r * E[len - 1]`` — the dominant term of the measured speedup;
-* **baked sentinel dump row** — score buffers are allocated once as
-  [n + 1, W] (row n = dump row), so sentinel scatter/gather indices need no
-  clipping and the SpMM kernel path never re-pads ``scores``
-  (``push_level_padded`` / ``spmm_ell_padded``);
+* **baked sentinel dump row** — score buffers are allocated once with
+  a dump row at row n, so sentinel scatter/gather indices need no
+  clipping and no level re-pads ``scores``;
+* **one push per level, picked from what the step observes**
+  (:func:`push_path`) — on a TPU the fused CSR Pallas level
+  (``kernels/lane_probe``) over a dst-sorted view of the COO graph, built
+  once per step; elsewhere, or when the frontier does not fit VMEM, the
+  XLA level (:func:`xla_level`) over the COO or ELL push graph;
 * **fused epilogue** — per-query segment reduction (owned-column sum), the
   1/n_r normalization, the diagonal fix-up and ``lax.top_k`` all run inside
   the same compiled step, with the [Q, n] accumulator donated by the caller.
@@ -122,8 +126,8 @@ def lane_refill(pos, widx, next_q, pool_len, qid, *, n_r):
     """Column bookkeeping for one level: finished-column detection plus
     sticky per-query refill from the pool.
 
-    Pure [W]-vector arithmetic — no score movement — so the fused Pallas
-    level kernels and the XLA level composition share it verbatim.
+    Pure [W]-vector arithmetic — no score movement — so every level
+    (the CSR kernel, the XLA level, the mesh levels) shares it verbatim.
     ``qid`` [W] is each column's owner (:func:`lane_columns`, a runtime
     array when the live count is data); ``next_q`` [Q] the per-query pool
     cursor, ``n_r`` for a slot that draws nothing.  Returns
@@ -166,15 +170,12 @@ def lane_thresholds(pos, *, sqrt_c: float, eps_p: float):
     )
 
 
-def push_path(g: Graph | EllGraph, width: int, *, use_kernel: bool) -> str:
+def push_path(g: Graph | EllGraph, width: int) -> str:
     """The push a fused serve step's probe levels run, from what the step
-    can observe: ``"ell_kernel"`` under ``use_kernel``; else, over a COO
-    push graph, ``"csr_kernel"`` where JAX runs on a TPU and the fp32
-    frontier of ``width`` lane columns fits the CSR kernel's on-chip
-    budget, and ``"coo_xla"`` otherwise (an ELL push graph: ``"ell_xla"``).
-    """
-    if use_kernel:
-        return "ell_kernel"
+    can observe: over a COO push graph, ``"csr_kernel"`` where JAX runs on
+    a TPU and the fp32 frontier of ``width`` lane columns fits the CSR
+    kernel's on-chip budget, and ``"coo_xla"`` otherwise; over an ELL push
+    graph, ``"ell_xla"``."""
     if isinstance(g, EllGraph):
         return "ell_xla"
     from repro.kernels.lane_probe.ops import csr_level_fits
@@ -193,7 +194,7 @@ def xla_level(g, scores, total, fin, u_p, u_prev, thr, *, cols, sqrt_c,
     scores = scores.at[u_p, cols].add(1.0)  # sentinel -> dump row
     if prune:
         scores = jnp.where(scores > thr[None, :], scores, 0.0)
-    scores = push_level_padded(g, scores, sqrt_c, use_kernel=False)
+    scores = push_level_padded(g, scores, sqrt_c)
     scores = scores.at[u_prev, cols].set(0.0)  # exclusion mask
     return scores, total
 
@@ -237,9 +238,7 @@ def fused_serve_impl(
     eps_p: float,
     eps_t: float,
     truncation_shift: bool,
-    use_kernel: bool,
     top_k: int,
-    kernel_dtype: str = "float32",
 ):
     """One fused serve step: sample pool -> compacted probe -> estimates.
 
@@ -252,14 +251,10 @@ def fused_serve_impl(
     estimate.  ``live == Q`` is the full schedule: query i owns the
     columns ``[i * lanes_q, (i + 1) * lanes_q)``.
 
-    ``use_kernel=True`` runs each probe level through the fused Pallas
-    lane-probe kernel (``kernels/lane_probe``) against the ELL push table;
-    ``kernel_dtype="bfloat16"`` additionally stores the score/accumulator
-    buffers in bf16 (accumulation stays fp32 on-chip).  Otherwise
     :func:`push_path` picks the level: on a TPU, when the frontier fits,
     the fused CSR kernel over a dst-sorted view of the COO graph, built
     once here (sums in CSR order: 1e-5 of the XLA level); else the XLA
-    level.  Returns
+    level.  The lane buffers are fp32.  Returns
     ``(acc, est, topk_idx, topk_vals, levels, lanes, walks_sampled)``; the
     top-k outputs are None when ``top_k == 0``.  ``levels`` (int32 scalar)
     counts the probe levels the step ran, ``lanes`` (int32 [Q]) the lane
@@ -274,11 +269,6 @@ def fused_serve_impl(
     lanes = _owner_sum(jnp.ones(w, jnp.int32), qid, q)
     # per-query pool cursors; a padding slot starts drained
     next0 = jnp.where(jnp.arange(q) < live, 0, n_r).astype(jnp.int32)
-    dtype = (
-        jnp.bfloat16
-        if (use_kernel and kernel_dtype == "bfloat16")
-        else jnp.float32
-    )
 
     # --- walk pool: the live slots' n_r walks each ----------------------
     pool, walks_sampled = live_walk_pool(
@@ -287,26 +277,8 @@ def fused_serve_impl(
     pool_len = (pool < n).sum(axis=1).astype(jnp.int32)
 
     # --- one probe level: deposit + inject + prune + push + exclude -------
-    path = push_path(g, w, use_kernel=use_kernel)
     rows = n + 1  # score buffer rows: the graph's and the dump row
-    if path == "ell_kernel":
-        from repro.kernels.lane_probe.ops import lane_probe_level
-
-        ell = g if isinstance(g, EllGraph) else eg
-        w_push = ell.inv_in_deg * sqrt_c
-        zrow = jnp.zeros((1, w), dtype)
-
-        def level_fn(scores, total, fin, u_p, u_prev, thr):
-            out, tot = lane_probe_level(
-                ell.in_nbrs, w_push, scores, scores[:n], total[:n],
-                fin, u_p, u_prev, thr,
-                row0=0, tab0=0, n_live=n, prune=eps_p > 0.0,
-            )
-            return (
-                jnp.concatenate([out, zrow]),
-                jnp.concatenate([tot, zrow]),
-            )
-    elif path == "csr_kernel":
+    if push_path(g, w) == "csr_kernel":
         from repro.kernels.lane_probe.ops import (
             csr_layout, csr_push_view, lane_probe_csr_level,
         )
@@ -353,15 +325,15 @@ def fused_serve_impl(
         jnp.zeros(w, jnp.int32),  # pos: all idle -> first iteration refills
         jnp.zeros(w, jnp.int32),  # widx
         next0,  # next_q
-        jnp.zeros((rows, w), dtype),  # scores (baked dump row)
-        jnp.zeros((rows, w), dtype),  # total (baked dump row)
+        jnp.zeros((rows, w), jnp.float32),  # scores (baked dump row)
+        jnp.zeros((rows, w), jnp.float32),  # total (baked dump row)
     )
     levels, pos, _, _, scores, total = jax.lax.while_loop(cond, body, state)
     # safety-net flush (no-op unless max_steps was hit)
     total = total + jnp.where((pos == 1)[None, :], scores, 0.0)
 
     # --- per-query segment reduction + epilogue ---------------------------
-    tot = total[:n].astype(jnp.float32)
+    tot = total[:n]
 
     def by_block():  # every slot live: query i owns lane block i
         return tot.reshape(n, q, wq).sum(axis=2).T
@@ -397,9 +369,7 @@ _fused_serve = partial(
         "eps_p",
         "eps_t",
         "truncation_shift",
-        "use_kernel",
         "top_k",
-        "kernel_dtype",
     ),
     donate_argnames=("acc",),
 )(fused_serve_impl)
@@ -413,10 +383,7 @@ def _query_keys(key: Array | None, keys: Array | None, q: int) -> Array:
     return jax.random.split(key, q)
 
 
-def _serve(
-    key, g, eg, us, params, *, k, lanes, use_kernel, kernel_dtype, n_r,
-    keys, info, live,
-):
+def _serve(key, g, eg, us, params, *, k, lanes, n_r, keys, info, live):
     """One jitted fused step for :func:`multi_source` (``k == 0``) and
     :func:`multi_source_topk`; returns ``(est, idx, vals)`` on the device
     and, when ``info`` is a dict, puts the step's probe-level count (int32
@@ -439,13 +406,11 @@ def _serve(
         eps_p=params.eps_p,
         eps_t=params.eps_t,
         truncation_shift=params.truncation_shift,
-        use_kernel=use_kernel,
         top_k=int(k),
-        kernel_dtype=kernel_dtype,
     )
     if info is not None:
         info["levels"] = levels
-        info["push_path"] = push_path(g, q * lanes_q, use_kernel=use_kernel)
+        info["push_path"] = push_path(g, q * lanes_q)
         info["lanes"] = owned
         info["walks_sampled"] = walks
     return est, idx, vals
@@ -459,8 +424,6 @@ def multi_source(
     params: ProbeSimParams,
     *,
     lanes: int = 256,
-    use_kernel: bool = False,
-    kernel_dtype: str = "float32",
     n_r: int | None = None,
     keys: Array | None = None,
     info: dict | None = None,
@@ -471,12 +434,8 @@ def multi_source(
     ``us`` is int32 [Q]; ``g`` is the push representation (COO or ELL), ``eg``
     the ELL table used for walk sampling.  ``lanes`` is the total lane-column
     width shared by the batch (each query owns ``lanes // Q`` columns, or
-    about ``lanes / live`` under ``live``).
-    ``use_kernel=True`` serves every probe level through the fused Pallas
-    lane-probe kernel (bitwise-equal to the XLA ELL path in fp32);
-    ``kernel_dtype="bfloat16"`` stores the lane buffers bf16 with fp32
-    accumulation.  ``n_r`` overrides ``params.n_r`` (anytime/budgeted
-    serving).  Pass per-query ``keys`` ([Q] typed key array) for
+    about ``lanes / live`` under ``live``).  ``n_r`` overrides
+    ``params.n_r`` (anytime/budgeted serving).  Pass per-query ``keys`` ([Q] typed key array) for
     batch-vs-serial determinism; otherwise ``key`` is split into Q streams.
     A dict passed as ``info`` receives ``"levels"``: the number of probe
     levels the step ran, as an int32 device scalar, ``"push_path"``: the
@@ -490,9 +449,8 @@ def multi_source(
     serves every ``live`` for a batch shape.
     """
     est, _, _ = _serve(
-        key, g, eg, us, params, k=0, lanes=lanes, use_kernel=use_kernel,
-        kernel_dtype=kernel_dtype, n_r=n_r, keys=keys, info=info,
-        live=live,
+        key, g, eg, us, params, k=0, lanes=lanes, n_r=n_r, keys=keys,
+        info=info, live=live,
     )
     return est
 
@@ -506,8 +464,6 @@ def multi_source_topk(
     params: ProbeSimParams,
     *,
     lanes: int = 256,
-    use_kernel: bool = False,
-    kernel_dtype: str = "float32",
     n_r: int | None = None,
     keys: Array | None = None,
     info: dict | None = None,
@@ -520,8 +476,7 @@ def multi_source_topk(
     for :func:`multi_source`.
     """
     _, idx, vals = _serve(
-        key, g, eg, us, params, k=k, lanes=lanes, use_kernel=use_kernel,
-        kernel_dtype=kernel_dtype, n_r=n_r, keys=keys, info=info,
-        live=live,
+        key, g, eg, us, params, k=k, lanes=lanes, n_r=n_r, keys=keys,
+        info=info, live=live,
     )
     return idx, vals

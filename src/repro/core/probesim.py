@@ -57,7 +57,6 @@ def single_source(
     *,
     variant: str = "telescoped",
     walk_chunk: int = 512,
-    use_kernel: bool = False,
 ) -> Array:
     """Approximate single-source SimRank: returns estimates [n] (entry u = 1).
 
@@ -82,7 +81,7 @@ def single_source(
         # telescoped probe and finalizes the estimate (DESIGN.md §3).
         return multi_source(
             key, g, eg, jnp.asarray([u], jnp.int32), params,
-            lanes=walk_chunk, use_kernel=use_kernel,
+            lanes=walk_chunk,
         )[0]
     elif variant in ("tree", "auto"):
         for ci, b in enumerate(_walk_chunks(params.n_r, walk_chunk)):
@@ -109,7 +108,6 @@ def single_source(
                 if dedup < 1.5:
                     total = total + probe_walks_telescoped(
                         g, walks, sqrt_c=sqrt_c, eps_p=params.eps_p,
-                        use_kernel=use_kernel,
                     ).sum(axis=1)
                     continue
             total = total + probe_tree_levels(
@@ -120,7 +118,6 @@ def single_source(
                 tuple(jnp.asarray(x) for x in tree.parent_node),
                 sqrt_c=sqrt_c,
                 eps_p=params.eps_p,
-                use_kernel=use_kernel,
             )
     elif variant == "randomized":
         walks = sample_walks(

@@ -107,7 +107,7 @@ def _ring_push_level(buf, src_l, dst_l, me, *, shards: int, rows: int,
     permute overlaps the next bucket's gather/scatter compute.  Returns the
     un-renormalized push accumulator [rows, C] in f32 (callers apply the
     sqrt(c)/in_deg weights).  Shared by the per-level walk probe and the
-    lane-batched serve kernel.
+    lane-batched serve probe.
 
     With ``counts_l`` (int32 [S], live edges per resident bucket) each
     bucket is walked in ``edge_chunk`` slices with a dynamic trip count, so
@@ -238,7 +238,6 @@ def probe_lanes_ring(
     sqrt_c: float,
     eps_p: float,
     sentinel: int,
-    use_kernel: bool = False,
 ) -> Array:
     """Lane-batched telescoped probe with the ring push; returns [n_pad, W].
 
@@ -249,14 +248,6 @@ def probe_lanes_ring(
     Lane columns replicate over the data axes — the batched program has no
     per-chunk column sharding, so ring serving composes with ANY (Q, n_r)
     instead of falling back on divisibility remainders.
-
-    ``use_kernel=True`` fuses the level prologue (deposit + inject + prune)
-    through the Pallas lane-probe kernel in its identity-gather form — the
-    push itself must stay the ring exchange (the kernel cannot gather
-    through a ppermute), so the renormalize + exclusion epilogue follows it
-    as before.  Bitwise-equal to the XLA ring level in fp32: the only
-    prepped values that differ (padding rows the kernel zeroes where the
-    XLA compare injects) land in the dropped scatter segment.
     """
     from repro.core.distributed import lane_level_xla, lane_probe_block
 
@@ -288,33 +279,9 @@ def probe_lanes_ring(
                                    counts_l=counts_l, edge_chunk=ch)
             return acc * w_l[:, None]
 
-        if use_kernel:
-            from repro.kernels.lane_probe.ops import lane_probe_level
-
-            ident = row0 + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, 1), 0
-            )  # own-row identity "neighbors" (global ids)
-            ones = jnp.ones((rows,), jnp.float32)
-            no_excl = jnp.full((w,), sentinel, jnp.int32)
-            rid = jax.lax.broadcasted_iota(jnp.int32, (rows, w), 0) + row0
-
-            def level_fn(scores, total, fin, u_p, u_prev, thr):
-                # fused prologue: deposit + inject + prune, identity gather
-                # over the resident block (table IS the block -> tab0 = 0);
-                # exclusion is deferred past the ring push
-                prep, total = lane_probe_level(
-                    ident, ones, scores, scores, total,
-                    fin, u_p, no_excl, thr,
-                    row0=row0, tab0=0, n_live=sentinel,
-                    prune=eps_p > 0.0,
-                )
-                scores = push_block(prep)
-                scores = jnp.where(rid == u_prev[None, :], 0.0, scores)
-                return scores, total
-        else:
-            level_fn = lane_level_xla(
-                push_block, row0=row0, rows=rows, w=w, eps_p=eps_p
-            )
+        level_fn = lane_level_xla(
+            push_block, row0=row0, rows=rows, w=w, eps_p=eps_p
+        )
 
         return lane_probe_block(
             level_fn, pool_l, plen_l,
@@ -330,8 +297,6 @@ def probe_lanes_ring(
         out_specs=P("model", None),
         # fully manual, like the epoch apply step and the spmd lane probe
         axis_names=set(mesh.axis_names),
-        # the Pallas interpreter cannot slice blocks of varying operands
-        check_vma=not use_kernel,
     )
     return fn(src_sh, dst_sh, w_full, pool, pool_len)
 

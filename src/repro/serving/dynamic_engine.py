@@ -69,7 +69,6 @@ class DynamicEngine:
         batch_q: int = 8,
         update_batch: int = 64,
         auto_regrow: bool = True,
-        use_kernel: bool = False,
     ):
         warnings.warn(
             "DynamicEngine is deprecated; use repro.api.SimRankSession.epoch "
@@ -85,7 +84,6 @@ class DynamicEngine:
             c=c, eps_a=eps_a, delta=delta, walk_chunk=walk_chunk,
             top_k=top_k, seed=seed, batch_q=batch_q,
             update_batch=update_batch, auto_regrow=auto_regrow,
-            use_kernel=use_kernel,
         )
         self._stats = DynamicStats()  # ONE live object (legacy contract)
 
@@ -159,14 +157,6 @@ class DynamicEngine:
     @auto_regrow.setter
     def auto_regrow(self, value: bool) -> None:
         self._session.auto_regrow = bool(value)
-
-    @property
-    def use_kernel(self) -> bool:
-        return self._session.use_kernel
-
-    @use_kernel.setter
-    def use_kernel(self, value: bool) -> None:
-        self._session.use_kernel = bool(value)
 
     def _refresh_stats(self) -> None:
         s = self._session.stats

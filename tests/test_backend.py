@@ -253,9 +253,6 @@ def test_sharded_rejects_bad_geometry(handle):
     with pytest.raises(ValueError, match="frontier_dtype"):
         ShardedBackend(handle.shard(shards=1), params=p,
                        frontier_dtype="float16")
-    # use_kernel=True is a working mesh path now (PR 10), not a rejection
-    be = ShardedBackend(handle.shard(shards=1), params=p, use_kernel=True)
-    assert be.use_kernel is True
     with pytest.raises(ValueError, match="model"):
         from repro.utils.jaxcompat import make_mesh
 

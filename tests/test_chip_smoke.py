@@ -2,7 +2,7 @@
 
 The script itself refuses to run off a TPU; these tests drive its phase
 functions directly (the toy graph, ``powerlaw_graph(200, 1500)``, the
-kernel in interpret mode, the sharded phase on four fake devices) so its
+CSR kernel in interpret mode, the sharded phase on four fake devices) so its
 control flow and checks are exercised on every run.
 """
 import importlib.util
@@ -70,9 +70,10 @@ def test_service_phase(smoke, graph200):
 
 def test_kernel_phase_interpret(smoke):
     assert jax.default_backend() == "cpu"
-    out = smoke.phase_kernel(64, 4, 16)
+    out = smoke.phase_kernel(64, 512, 16)
     assert not out["native"]
-    assert out["max_abs_diff_scores"] == out["max_abs_diff_total"] == 0.0
+    assert out["max_abs_diff_scores"] <= smoke.KERNEL_TOL
+    assert out["max_abs_diff_total"] == 0.0  # the deposit is one add
 
 
 _SHARDED_SCRIPT = r"""

@@ -166,7 +166,7 @@ def test_counter_leaves_answers_bitwise_unchanged(handle, top_k):
     static = dict(
         n_r=128, lanes_q=64 // q, max_len=p.max_len, sqrt_c=p.sqrt_c,
         eps_p=p.eps_p, eps_t=p.eps_t, truncation_shift=p.truncation_shift,
-        use_kernel=False, top_k=top_k,
+        top_k=top_k,
     )
     g, eg = handle.g, handle.eg
 

@@ -52,43 +52,6 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_lane_kernel_lowers_at_bench_shape(one_chip):
-    from repro.kernels.lane_probe.lane_probe import lane_probe_pallas
-
-    R, K, T, W = 4096, 16, 4097, 256
-    i32, f32 = jnp.int32, jnp.float32
-    args = (
-        _sds((R, K), i32, one_chip), _sds((R,), f32, one_chip),
-        _sds((2,), i32, one_chip), *(_sds((W,), i32, one_chip),) * 3,
-        _sds((W,), f32, one_chip), _sds((T, W), f32, one_chip),
-        _sds((R, W), f32, one_chip), _sds((R, W), f32, one_chip),
-    )
-    compiled = jax.jit(
-        lambda *a: lane_probe_pallas(*a, n_live=R, prune=True,
-                                     interpret=False)
-    ).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_lane_kernel_refuses_hepph_k(one_chip):
-    from repro.kernels.lane_probe.ops import lane_probe_level
-
-    n, W = HEPPH_N, 256
-    args = (
-        _sds((n, HEPPH_K), jnp.int32, one_chip),
-        _sds((n,), jnp.float32, one_chip),
-        *(_sds(s, jnp.float32, one_chip) for s in ((n + 1, W), (n, W), (n, W))),
-        _sds((W,), jnp.bool_, one_chip),
-        *(_sds((W,), jnp.int32, one_chip),) * 2,
-        _sds((W,), jnp.float32, one_chip),
-    )
-    with pytest.raises(ValueError, match="use_kernel=False"):
-        jax.jit(
-            lambda *a: lane_probe_level(*a, row0=0, tab0=0, n_live=n,
-                                        prune=True, interpret=False)
-        ).lower(*args)
-
-
 def test_fused_serve_fits_one_chip_at_hepph(one_chip):
     from repro.core.multisource import _fused_serve
     from repro.core.params import make_params
@@ -108,8 +71,7 @@ def test_fused_serve_fits_one_chip_at_hepph(one_chip):
     compiled = _fused_serve.lower(
         keys, g, eg, s((q,)), s((q, n), jnp.float32), s(()),
         n_r=p.n_r, lanes_q=16, max_len=p.max_len, sqrt_c=p.sqrt_c,
-        eps_p=p.eps_p, eps_t=p.eps_t, truncation_shift=False,
-        use_kernel=False, top_k=50,
+        eps_p=p.eps_p, eps_t=p.eps_t, truncation_shift=False, top_k=50,
     ).compile()
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -150,6 +112,8 @@ def test_sharded_serve_step_compiles_on_four_chips(topo):
 # the benchmark's realized graphs (bench/configs): cit-hepph, and
 # wiki-vote at its COO capacity (m + 1,024 spare slots)
 BENCH_GRAPHS = {"hepph": (34_546, 421_578), "wiki": (7_115, 104_713)}
+# their ELL widths (bench/configs realized max_in_degree / ell_width)
+BENCH_ELL_K = {"hepph": 1_139, "wiki": 2_669}
 
 
 def _coo_shapes(n, cap, sharding):
@@ -189,20 +153,54 @@ def test_csr_level_lowers_at_bench_shape(one_chip, cell):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("cell", sorted(BENCH_GRAPHS))
+def test_csr_fused_serve_fits_one_chip(one_chip, monkeypatch, cell):
+    """The fused serve step on the CSR path, as a TPU runs it, compiles
+    for the chip at the benchmark's realized shapes (16 queries, W = 256,
+    the Thm-1 walk budget, top-50) and fits one chip's HBM; the level is
+    the native kernel."""
+    from repro.core.multisource import _fused_serve, push_path
+    from repro.core.params import make_params
+    from repro.graph.structs import EllGraph
+
+    n, cap = BENCH_GRAPHS[cell]
+    k, q = BENCH_ELL_K[cell], 16
+    s = lambda shape, dt=jnp.int32: _sds(shape, dt, one_chip)  # noqa: E731
+    g = _coo_shapes(n, cap, one_chip)
+    eg = EllGraph(in_nbrs=s((n, k)), in_deg=s((n,)), n=n, k_max=k,
+                  version=s(()), overflow=s((), jnp.bool_))
+    p = make_params(n, c=0.6, eps_a=0.1, delta=0.01)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert push_path(g, q * 16) == "csr_kernel"
+    jax.clear_caches()  # a traced step keeps the path it took
+    try:
+        compiled = _fused_serve.lower(
+            s((q,), jax.random.key(0).dtype), g, eg, s((q,)),
+            s((q, n), jnp.float32), s(()),
+            n_r=p.n_r, lanes_q=16, max_len=p.max_len, sqrt_c=p.sqrt_c,
+            eps_p=p.eps_p, eps_t=p.eps_t, truncation_shift=False, top_k=50,
+        ).compile()
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 4 * n * k <= used < HBM_BYTES
+
+
 def test_push_path_follows_the_frontier(monkeypatch):
     """On a TPU the default path takes the CSR kernel where the fp32
     frontier fits VMEM (both benchmark graphs) and keeps the XLA COO level
-    where it does not (LiveJournal's 4.8M rows); use_kernel keeps the ELL
-    kernel; off the TPU the XLA COO level."""
+    where it does not (LiveJournal's 4.8M rows); off the TPU the XLA COO
+    level."""
     from repro.core.multisource import push_path
 
     cpu = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
     lj = _coo_shapes(4_847_571, 68_993_773, cpu)
     hepph = _coo_shapes(*BENCH_GRAPHS["hepph"], cpu)
-    assert push_path(hepph, 256, use_kernel=False) == "coo_xla"
+    assert push_path(hepph, 256) == "coo_xla"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert push_path(hepph, 256, use_kernel=False) == "csr_kernel"
+    assert push_path(hepph, 256) == "csr_kernel"
     wiki = _coo_shapes(*BENCH_GRAPHS["wiki"], cpu)
-    assert push_path(wiki, 256, use_kernel=False) == "csr_kernel"
-    assert push_path(lj, 256, use_kernel=False) == "coo_xla"
-    assert push_path(hepph, 256, use_kernel=True) == "ell_kernel"
+    assert push_path(wiki, 256) == "csr_kernel"
+    assert push_path(lj, 256) == "coo_xla"
